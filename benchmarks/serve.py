@@ -35,6 +35,7 @@ from repro.config import SealConfig
 from repro.configs import get_reduced
 from repro.launch.serve import drive, poisson_arrivals
 from repro.models import transformer as T
+from repro.runtime import compile_cache
 from repro.serve.engine import GroupServeEngine, ServeEngine
 
 MAX_LEN = 96
@@ -180,6 +181,7 @@ def serve_bench(arch: str = "internlm2_1_8b", requests: int = 48,
 
 
 def main(sweep_slots=None):
+    compile_cache.enable()
     res = serve_bench(**({} if sweep_slots is None
                          else {"sweep_slots": sweep_slots}))
     print(json.dumps(res, indent=1))

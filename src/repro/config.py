@@ -267,14 +267,28 @@ class RunConfig:
     train: TrainConfig = TrainConfig()
 
 
-# v5e hardware constants for roofline (per chip)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GiB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect (= 4 links x 50 GB/s).
 HW = {
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link
-    "vmem_bytes": 128 * 2**20,
-    "hbm_bytes": 16 * 2**30,
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s
+        "hbm_bw": 819e9,             # B/s
+        "ici_bw": 50e9,              # B/s per link
+        "hbm_bytes": 16 * 2**30,
+    },
 }
+
+
+def hw_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; a chip not in ``HW`` is an
+    error, never a default."""
+    try:
+        return HW[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(HW)}") from None
 
 # Paper's modeled GPU constants (GTX480-class) for the analytic perfmodel
 PAPER_GPU = {

@@ -186,23 +186,33 @@ def _quarter(a, b, c, d):
 
 
 def chacha20_block(key_words, counters, nonce_words):
-    """ChaCha20 keystream blocks.
+    """ChaCha20 keystream blocks: (n, 16) uint32 (= n x 64B keystream).
+    Arguments as for ``chacha20_words``."""
+    return chacha20_words(key_words, counters, nonce_words).T
+
+
+def chacha20_words(key_words, counters, nonce_words):
+    """ChaCha20 keystream blocks, word-major: (16, n) uint32, row w holding
+    word w of every block. A TPU lays an (n, 16) array out padded to 128
+    lanes, 8x its size; consumers that can take the words as rows avoid it.
 
     key_words: (8,) uint32; counters: (n,) uint32;
-    nonce_words: (3,) uint32 (shared) or (n, 3) uint32 (per-block — used by
-    the engines to fold the line address + write-counter into the OTP).
-    Returns (n, 16) uint32 (= n x 64B keystream).
+    nonce_words: (3,) uint32 (shared), (n, 3) uint32 (per block), or a
+    tuple of three words, each a scalar or an (n,) vector (used by the
+    engines to fold the line address + write-counter into the OTP; separate
+    vectors avoid the (n, 3) array, which a TPU pads to 128 lanes).
     """
     n = counters.shape[0]
     key_words = jnp.asarray(key_words, jnp.uint32)
-    nonce_words = jnp.asarray(nonce_words, jnp.uint32)
-    if nonce_words.ndim == 1:
-        nonce_words = jnp.broadcast_to(nonce_words[None], (n, 3))
+    if isinstance(nonce_words, (tuple, list)):
+        nonce = [jnp.asarray(v, jnp.uint32) for v in nonce_words]
+    else:                                   # (3,) shared or (n, 3) per block
+        nonce_words = jnp.asarray(nonce_words, jnp.uint32)
+        nonce = [nonce_words[..., i] for i in range(3)]
     state = [jnp.broadcast_to(jnp.uint32(_CHACHA_CONST[i]), (n,)) for i in range(4)]
     state += [jnp.broadcast_to(key_words[i], (n,)) for i in range(8)]
     state += [counters.astype(jnp.uint32)]
-    state += [nonce_words[:, i] for i in range(3)]
-    state = jnp.stack(state, axis=0)                # (16, n)
+    state += [jnp.broadcast_to(v, (n,)) for v in nonce]
 
     col = ((0, 4, 8, 12), (1, 5, 9, 13), (2, 6, 10, 14), (3, 7, 11, 15))
     diag = ((0, 5, 10, 15), (1, 6, 11, 12), (2, 7, 8, 13), (3, 4, 9, 14))
@@ -210,21 +220,15 @@ def chacha20_block(key_words, counters, nonce_words):
     def dround(_, x):
         # rolled into a fori_loop: keeps the HLO ~10x smaller, which is what
         # makes per-step in-graph decryption of a whole model compilable.
-        for idx in (col, diag):
-            a = jnp.stack([x[i[0]] for i in idx])
-            b = jnp.stack([x[i[1]] for i in idx])
-            c = jnp.stack([x[i[2]] for i in idx])
-            d = jnp.stack([x[i[3]] for i in idx])
-            a, b, c, d = _quarter(a, b, c, d)
-            vals = jnp.concatenate([a, b, c, d], axis=0)
-            order = sum(([i[0] for i in idx], [i[1] for i in idx],
-                         [i[2] for i in idx], [i[3] for i in idx]), [])
-            x = x.at[jnp.asarray(order)].set(vals)
-        return x
+        # The 16 state words are carried as separate (n,) vectors, so a
+        # round is elementwise work only (no gathers or scatters).
+        x = list(x)
+        for a, b, c, d in col + diag:
+            x[a], x[b], x[c], x[d] = _quarter(x[a], x[b], x[c], x[d])
+        return tuple(x)
 
-    x = jax.lax.fori_loop(0, 10, dround, state)
-    out = x + state
-    return out.T                                    # (n, 16) u32
+    x = jax.lax.fori_loop(0, 10, dround, tuple(state))
+    return jnp.stack([xi + si for xi, si in zip(x, state)], axis=0)
 
 
 def chacha20_keystream_u32(key_words, n_words: int, nonce_words, counter0: int = 0):

@@ -18,6 +18,7 @@ stored in plaintext (safe without the key, paper §2.3).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -62,17 +63,14 @@ def words_to_tensor(words, shape, dtype):
 def _line_otp(key_words, line_addrs, write_counters, nonce2):
     """128 B OTP per line: two ChaCha blocks with
     nonce = (line_addr, nonce2[0], nonce2[1]), counter = wc*2 + subblock."""
-    L = line_addrs.shape[0]
-    addrs = jnp.repeat(line_addrs.astype(jnp.uint32), 2)
-    wc = jnp.repeat(write_counters.astype(jnp.uint32), 2)
-    sub = jnp.tile(jnp.arange(2, dtype=jnp.uint32), L)
-    counters = wc * jnp.uint32(2) + sub
-    nonces = jnp.stack([
-        addrs,
-        jnp.broadcast_to(jnp.uint32(nonce2[0]), addrs.shape),
-        jnp.broadcast_to(jnp.uint32(nonce2[1]), addrs.shape)], axis=1)
-    ks = C.chacha20_block(key_words, counters, nonces)       # (2L, 16)
-    return ks.reshape(L, CL.WORDS_PER_LINE)
+    wc = write_counters.astype(jnp.uint32) * jnp.uint32(2)
+    nonces = (line_addrs.astype(jnp.uint32), nonce2[0], nonce2[1])
+    # word-major halves, one per sub-block, stacked along words: the one
+    # transpose at the end folds into the (L, 32) layout, where an (L, 16)
+    # intermediate would be materialized lane-padded on a TPU
+    halves = [C.chacha20_words(key_words, wc + jnp.uint32(s), nonces)
+              for s in range(2)]
+    return jnp.concatenate(halves, axis=0).T                 # (L, 32)
 
 
 @dataclasses.dataclass
@@ -196,6 +194,31 @@ class DirectEngine(EngineProtocol):
         return words_to_tensor(words.reshape(-1)[:s.orig_len], s.shape, s.dtype)
 
 
+def _nonce_key(nonce3) -> Tuple[int, ...]:
+    return tuple(int(v) for v in np.asarray(nonce3, np.uint32).reshape(-1))
+
+
+@functools.partial(jax.jit, static_argnames=("nonce3", "bk", "bn",
+                                             "decrypt"))
+def _tiles_xor(key_words, w, row_mask, write_counter, *, nonce3, bk, bn,
+               decrypt):
+    """Tile-sealed (un)seal of a (K, N) leaf or a stack of them; the stack
+    is walked with ``lax.map`` so only one slice's keystream is live."""
+    from repro.kernels import ref as _ref   # oracle owns the derivation
+    fn = _ref.unseal_weights_ref if decrypt else _ref.seal_weights_ref
+    nonce = jnp.asarray(nonce3, jnp.uint32)
+
+    def one(args):
+        w2d, mask, wc = args
+        return fn(w2d, key_words, nonce, bk, bn, mask, wc)
+
+    wc = jnp.asarray(write_counter, jnp.uint32)
+    if w.ndim == 2:
+        return one((w, row_mask, wc))
+    wc = jnp.broadcast_to(wc, w.shape[:1])
+    return jax.lax.map(one, (w, row_mask, wc))
+
+
 class _CtrBase(EngineProtocol):
     supports_fused = True
 
@@ -211,19 +234,21 @@ class _CtrBase(EngineProtocol):
     # counter state is the per-tensor write counter, which is colocated by
     # construction — the per-tile counters are implicit in the address) ----
 
-    def encrypt_tiles(self, w2d, nonce3, row_mask, write_counter: int,
-                      bk: int, bn: int):
-        """(K, N) float32 -> (K, N) u32 ciphertext; rows where ``row_mask``
-        is False stay plaintext (SE bypass, paper §3.3)."""
-        from repro.kernels import ref as _ref   # oracle owns the derivation
-        return _ref.seal_weights_ref(w2d, self.key_words, jnp.asarray(
-            nonce3, jnp.uint32), bk, bn, row_mask, write_counter)
+    def encrypt_tiles(self, w, nonce3, row_mask, write_counter, bk: int,
+                      bn: int):
+        """(..., K, N) float32 -> u32 ciphertext of the same shape; rows
+        where ``row_mask`` (..., K) is False stay plaintext (SE bypass,
+        paper §3.3). A stacked leaf seals slice by slice under its own
+        ``write_counter`` (...,), one slice's keystream at a time."""
+        return _tiles_xor(self.key_words, w, row_mask, write_counter,
+                          nonce3=_nonce_key(nonce3), bk=bk, bn=bn,
+                          decrypt=False)
 
-    def decrypt_tiles(self, ct2d, nonce3, row_mask, write_counter: int,
-                      bk: int, bn: int):
-        from repro.kernels import ref as _ref
-        return _ref.unseal_weights_ref(ct2d, self.key_words, jnp.asarray(
-            nonce3, jnp.uint32), bk, bn, row_mask, write_counter)
+    def decrypt_tiles(self, ct, nonce3, row_mask, write_counter, bk: int,
+                      bn: int):
+        return _tiles_xor(self.key_words, ct, row_mask, write_counter,
+                          nonce3=_nonce_key(nonce3), bk=bk, bn=bn,
+                          decrypt=True)
 
     # ---- paged KV-cache block layout (cache analogue of the tile scheme:
     # keystream from the block's pool address + write counter + layer id;

@@ -150,10 +150,8 @@ def mac_pads(key_words, nonce3, addrs, wcs, lids=0):
     if shape == ():
         shape = (1,)
     a, w, l = (jnp.broadcast_to(t, shape).reshape(-1) for t in (a, w, l))
-    nonces = jnp.stack([
-        jnp.uint32(nonce3[0]) ^ l,
-        jnp.uint32(nonce3[1]) ^ w,
-        jnp.broadcast_to(jnp.uint32(nonce3[2]), a.shape)], axis=1)
+    nonces = (jnp.uint32(nonce3[0]) ^ l, jnp.uint32(nonce3[1]) ^ w,
+              nonce3[2])
     pads = C.chacha20_block(jnp.asarray(key_words, jnp.uint32), a, nonces)
     return pads[:, 0].reshape(shape)
 
@@ -203,21 +201,31 @@ def tile_tags(ctx: MacContext, ct, row_mask, wc, bk: int, bn: int,
     wc: (...,) write counter per stacked slice. The message is the masked
     ciphertext — SE-plaintext (bypass) rows are zeroed and therefore out of
     MAC scope *by construction*; the pad binds (tile address, wc, tweak).
-    Returns (..., K//bk, N//bn) u32.
+    Returns (..., K//bk, N//bn) u32. The hash walks one row of tiles at a
+    time (and a stack one slice at a time), which bounds its temporaries to
+    one (bk, N) row.
     """
     ct = jnp.asarray(ct, jnp.uint32)
     mask = jnp.asarray(row_mask, bool)
-    ct = jnp.where(mask[..., :, None], ct, jnp.uint32(0))
-    lead = ct.shape[:-2]
-    k, n = ct.shape[-2:]
+    if ct.ndim > 2:
+        wcs = jnp.broadcast_to(jnp.asarray(wc, jnp.uint32), ct.shape[:-2])
+        return jax.lax.map(lambda a: tile_tags(ctx, *a, bk, bn, tweak),
+                           (ct, mask, wcs))
+    k, n = ct.shape
     nk, nn = k // bk, n // bn
-    tiles = ct.reshape(lead + (nk, bk, nn, bn))
-    tiles = jnp.moveaxis(tiles, -3, -2).reshape(lead + (nk, nn, bk * bn))
-    tag = uhash(ctx.hash_keys(bk * bn), tiles)
+    keys = ctx.hash_keys(bk * bn)
+
+    def row(args):
+        ct_row, mask_row = args                      # (bk, N), (bk,)
+        ct_row = jnp.where(mask_row[:, None], ct_row, jnp.uint32(0))
+        tiles = jnp.moveaxis(ct_row.reshape(bk, nn, bn), 0, 1)
+        return uhash(keys, tiles.reshape(nn, bk * bn))
+
+    tag = jax.lax.map(row, (ct.reshape(nk, bk, n), mask.reshape(nk, bk)))
     addr = jnp.arange(nk * nn, dtype=jnp.uint32).reshape(nk, nn)
-    wcb = jnp.asarray(wc, jnp.uint32).reshape(lead + (1, 1))
     return tag ^ mac_pads(ctx.key_words, tuple(
-        int(a) ^ int(b) for a, b in zip(ctx.nonce3, tweak)), addr, wcb, 0)
+        int(a) ^ int(b) for a, b in zip(ctx.nonce3, tweak)), addr,
+        jnp.asarray(wc, jnp.uint32), 0)
 
 
 def line_tags(ctx: MacContext, records, tweak=(0, 0, 0)):

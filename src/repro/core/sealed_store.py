@@ -10,19 +10,21 @@ per leaf:
   the engine is counter-mode: they flow *still sealed* through the jitted
   serving graph into ``kernels.sealed_matmul`` and are decrypted in-register
   under their SE row masks — the plaintext weight never exists in HBM;
-* everything else (norms, embeddings, MoE experts, recurrent/SSM weights)
-  gets the **line-packed at-rest layout** and is decrypted eagerly in-graph.
+* the token embedding is tile-sealed too (an unpadded layout on a TPU)
+  but decrypted eagerly in-graph, since its consumer is a gather;
+* everything else (norms, MoE experts, recurrent/SSM weights) gets the
+  **line-packed at-rest layout** and is decrypted eagerly in-graph.
 
 ``unseal_params`` decrypts every leaf (both layouts, jittable);
-``fused_params`` decrypts only the line-layout leaves and passes tile-sealed
-leaves through as ``SealedTensor`` — that is the serving hot path, and
+``fused_params`` passes the matmul leaves through as ``SealedTensor`` and
+decrypts the rest — that is the serving hot path, and
 ``plaintext_bytes_materialized`` is exactly the per-step metric it buys.
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,15 +54,15 @@ class SealedParams:
         return P.plan_totals(self.plans)["enc_fraction"]
 
     def fused_paths(self):
-        return [p for p, t in self.tensors.items()
-                if t.meta.layout == "tiles"]
+        return [p for p, t in self.tensors.items() if t.meta.fused]
 
     def plaintext_bytes_materialized(self) -> int:
         """Plaintext bytes the decrypt-on-use graph materializes per step:
-        only the eagerly-decrypted (line-layout) leaf fraction; tile-sealed
-        leaves are decrypted in-register inside the matmul."""
-        return sum(t.logical_bytes() for t in self.tensors.values()
-                   if t.meta.layout != "tiles")
+        only the eagerly-decrypted leaf fraction; fused leaves are decrypted
+        in-register inside the matmul."""
+        fused = set(self.fused_paths())
+        return sum(t.logical_bytes() for p, t in self.tensors.items()
+                   if p not in fused)
 
 
 def _nonce2(path: str) -> Tuple[int, int]:
@@ -132,24 +134,48 @@ def line_flags_from_mask(mask_elems, dtype, n_lines: int) -> jnp.ndarray:
 _FUSED_LEAVES = {("attn", "wq"), ("attn", "wk"), ("attn", "wv"),
                  ("attn", "wo"), ("mlp", "wi"), ("mlp", "wg"),
                  ("mlp", "wo"), ("head", "w")}
+# Leaves stored tile-sealed but decrypted eagerly in-graph (their consumer
+# is a gather, not a matmul). The tile layout keeps a (V, D) table as V x D
+# words, which a TPU holds unpadded and (un)seals one row of tiles at a
+# time; the 34-word line records of the line layout are lane-padded there,
+# and (un)sealing a full-width embedding through them takes several GB.
+_EAGER_TILE_LEAVES = {("embed", "w")}
+
+
+class TileGeometry(NamedTuple):
+    n_batch: int          # leading stack axes
+    k_ndim: int           # contraction (row) axes
+    n_out: int            # trailing output axes
+    k: int
+    n: int
+    bk: int
+    bn: int
+    fused: bool           # reaches its matmul still sealed; else eager
 
 
 def _pick_block(dim: int) -> Optional[int]:
-    for b in (128, 64, 32, 16, 8):
+    # a tile's pad is 16 keystream-word planes stacked along its rows, so
+    # a tile side is a multiple of 16 (``kernels.ref.tile_counters``)
+    for b in (128, 64, 32, 16):
         if dim % b == 0:
             return b
     return None
 
 
-def tile_geometry(path: Tuple[str, ...], shape, dtype, seal: SealConfig):
-    """(n_batch, k_ndim, n_out, K, N, bk, bn) if the leaf can take the
-    tile-sealed matmul layout, else None. Pure function of shapes, so the
-    dry-run can build spec-level sealed trees without allocating."""
+def tile_geometry(path: Tuple[str, ...], shape, dtype,
+                  seal: SealConfig) -> Optional[TileGeometry]:
+    """The tile layout of the leaf, or None if it takes the line layout.
+    ``fused`` says whether it flows still sealed into
+    ``SealedTensor.matmul`` or is decrypted eagerly in-graph; every reader
+    of that decision (``fused_paths``, ``fused_params``, the dry-run) takes
+    it from here. Pure function of shapes, so the dry-run can build
+    spec-level sealed trees without allocating."""
     if not seal.fuse_decrypt or seal.mode not in ("counter", "coloe"):
         return None
-    parent = path[-2] if len(path) >= 2 else ""
-    if (parent, path[-1]) not in _FUSED_LEAVES and \
-            (path[0], path[-1]) not in _FUSED_LEAVES:
+    keys = {(path[-2] if len(path) >= 2 else "", path[-1]),
+            (path[0], path[-1])}
+    fused = bool(keys & _FUSED_LEAVES)
+    if not fused and not keys & _EAGER_TILE_LEAVES:
         return None
     if jnp.dtype(dtype).itemsize != 4:
         return None                       # payload is the u32 bitcast
@@ -169,7 +195,7 @@ def tile_geometry(path: Tuple[str, ...], shape, dtype, seal: SealConfig):
     bk, bn = _pick_block(k), _pick_block(n)
     if bk is None or bn is None:
         return None
-    return nb, nk, n_out, k, n, bk, bn
+    return TileGeometry(nb, nk, n_out, k, n, bk, bn, fused)
 
 
 # --------------------------------------------------------------------------
@@ -199,7 +225,7 @@ def _seal_lines(eng, seal, leaf, plan, path) -> SealedTensor:
 
 
 def _seal_tiles(eng, seal, leaf, plan, path, geom) -> SealedTensor:
-    nb, nk, n_out, k, n, bk, bn = geom
+    nb, nk, n_out, k, n, bk, bn, fused = geom
     nonce3 = _nonce3(path)
     shape = leaf.shape
     if plan.mask is not None:
@@ -207,31 +233,30 @@ def _seal_tiles(eng, seal, leaf, plan, path, geom) -> SealedTensor:
     else:
         mask = jnp.ones(shape[:nb] + (k,), bool)
     key_arr = jnp.asarray(eng.key_words, jnp.uint32)
-    if nb == 1:
-        # one write-counter per stack slice: the (key, nonce, counter)
-        # triple — hence the OTP — is never reused across layers
-        slices = [eng.encrypt_tiles(leaf[i].reshape(k, n), nonce3, mask[i],
-                                    i, bk, bn) for i in range(shape[0])]
-        payload = jnp.stack(slices).reshape(shape)
-        wc = jnp.arange(shape[0], dtype=jnp.uint32)
-        key_c = jnp.broadcast_to(key_arr, (shape[0], 8))
-        ct2d = payload.reshape(shape[0], k, n)
-    else:
-        payload = eng.encrypt_tiles(leaf.reshape(k, n), nonce3, mask,
-                                    0, bk, bn).reshape(shape)
-        wc = jnp.zeros((), jnp.uint32)
-        key_c = key_arr
-        ct2d = payload.reshape(k, n)
+    lead = shape[:nb]
+    # one write-counter per stack slice: the (key, nonce, counter) triple —
+    # hence the OTP — is never reused across layers
+    wc = (jnp.arange(shape[0], dtype=jnp.uint32) if nb
+          else jnp.zeros((), jnp.uint32))
+    key_c = jnp.broadcast_to(key_arr, lead + (8,))
+    ct2d = eng.encrypt_tiles(leaf.reshape(lead + (k, n)), nonce3, mask, wc,
+                             bk, bn)
+    payload = ct2d.reshape(shape)
     meta = SealMeta(scheme=eng.name, layout="tiles",
                     dtype=str(jnp.dtype(leaf.dtype)), nonce=nonce3,
                     shape=tuple(shape), n_batch=nb, k_ndim=nk, n_out=n_out,
-                    bk=bk, bn=bn)
+                    bk=bk, bn=bn, fused=fused)
     macs = (M.tile_tags(eng.mac_ctx, ct2d, mask, wc, bk, bn, tweak=nonce3)
             if seal.verify else None)
     return SealedTensor(payload, None, mask, key_c, wc, meta, macs=macs)
 
 
-def seal_params(params, seal: SealConfig, key_bytes: bytes) -> SealedParams:
+def seal_params(params, seal: SealConfig, key_bytes: bytes, *,
+                consume: bool = False) -> SealedParams:
+    """Seal every leaf of ``params``. With ``consume`` each plaintext leaf
+    is deleted as soon as its ciphertext exists, so the device never holds
+    the whole model twice and the plaintext does not outlive the seal
+    (the caller's ``params`` are dead afterwards)."""
     plans = P.make_plan(params, seal)
     eng = E.make_engine(seal.mode, key_bytes)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
@@ -246,6 +271,9 @@ def seal_params(params, seal: SealConfig, key_bytes: bytes) -> SealedParams:
             tensors[path] = _seal_tiles(eng, seal, leaf, plan, path, geom)
         else:
             tensors[path] = _seal_lines(eng, seal, leaf, plan, path)
+        if consume:
+            jax.block_until_ready(tensors[path])
+            leaf.delete()
     return SealedParams(tensors, plans, treedef, seal)
 
 
@@ -259,15 +287,10 @@ def _unseal_tensor(eng, st: SealedTensor):
         nb = m.n_batch
         k = int(np.prod(m.shape[nb:nb + m.k_ndim]))
         n = int(np.prod(m.shape[nb + m.k_ndim:]))
-        if nb == 1:
-            outs = [eng.decrypt_tiles(st.payload[i].reshape(k, n), m.nonce,
-                                      st.row_mask[i], i, m.bk, m.bn)
-                    for i in range(m.shape[0])]
-            w = jnp.stack(outs).reshape(m.shape)
-        else:
-            w = eng.decrypt_tiles(st.payload.reshape(k, n), m.nonce,
-                                  st.row_mask, 0, m.bk, m.bn).reshape(m.shape)
-        return w.astype(jnp.dtype(m.dtype))
+        lead = m.shape[:nb]
+        w = eng.decrypt_tiles(st.payload.reshape(lead + (k, n)), m.nonce,
+                              st.row_mask, st.wc, m.bk, m.bn)
+        return w.reshape(m.shape).astype(jnp.dtype(m.dtype))
     buf = E.SealedBuffer(m.scheme, st.payload, st.counters, m.orig_len,
                          m.shape, jnp.dtype(m.dtype), m.nonce)
     return eng.decrypt(buf)
@@ -287,12 +310,13 @@ def unseal_params(sp: SealedParams, key_bytes: bytes):
 
 
 def fused_params(sp: SealedParams, key_bytes: bytes):
-    """The serving view: line-layout leaves decrypt eagerly; tile-sealed
-    leaves pass through STILL SEALED and are decrypted in-register by
-    ``kernels.sealed_matmul`` at their consumption site. (Ordering: see
+    """The serving view: the matmul leaves pass through STILL SEALED and
+    are decrypted in-register by ``kernels.sealed_matmul`` at their
+    consumption site; every other leaf decrypts eagerly. (Ordering: see
     ``unseal_params``.)"""
     eng = E.make_engine(sp.seal.mode, key_bytes)
-    flat = [sp.tensors[p] if sp.tensors[p].meta.layout == "tiles"
+    fused = set(sp.fused_paths())
+    flat = [sp.tensors[p] if p in fused
             else _unseal_tensor(eng, sp.tensors[p]) for p in sp.plans]
     return jax.tree_util.tree_unflatten(sp.treedef, flat)
 

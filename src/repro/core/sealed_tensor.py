@@ -54,6 +54,7 @@ class SealMeta:
     n_out: int = 1                 # tiles: trailing output axes
     bk: int = 128                  # tiles: contraction tile
     bn: int = 128                  # tiles: output tile
+    fused: bool = False            # tiles: reaches its matmul still sealed
 
 
 class SealedTensor:

@@ -1,7 +1,9 @@
 """jit'd public wrappers over the Pallas kernels (+ faithful unfused
 baselines used for before/after comparisons in §Perf).
 
-``interpret`` defaults to True on CPU (this container) and False on TPU.
+Interpret mode is chosen here and nowhere else: ``_default_interpret`` is
+True only on the CPU backend (tests), never on a TPU. The raw kernels take
+``interpret`` as a required argument.
 """
 from __future__ import annotations
 
@@ -12,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import chacha20 as _cc
+from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as _ref
 from repro.kernels import sealed_matmul as _sm
 
@@ -20,7 +23,7 @@ def _default_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def keystream(key_words, nonce_words, n_blocks: int, *, tile: int = 256,
+def keystream(key_words, nonce_words, n_blocks: int, *, tile: int = 1024,
               counter0: int = 0, interpret=None):
     """(16, n_blocks) u32 ChaCha20 keystream via the Pallas kernel."""
     interpret = _default_interpret() if interpret is None else interpret
@@ -59,6 +62,16 @@ def sealed_matmul(x, w_ct, row_mask, key_words, nonce_words,
                             bm=bm, bk=bk, bn=bn, interpret=interpret,
                             compute_dtype=compute_dtype)
     return out[:m]
+
+
+def flash_attention(q, k, v, *, scale: float, softcap: float = 0.0,
+                    window: int = 0, bq: int = 128, bkv: int = 128,
+                    interpret=None):
+    """Causal flash attention (see ``kernels.flash_attention``)."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _fa.flash_attention(q, k, v, scale=scale, softcap=softcap,
+                               window=window, bq=bq, bkv=bkv,
+                               interpret=interpret)
 
 
 def decrypt_then_matmul(x, w_ct, row_mask, key_words, nonce_words,

@@ -4,6 +4,9 @@ MUST set the forced device count before ANY other import — jax locks the
 device count on first init.
 """
 import os
+# a host-device compile tool: it must never take a TPU, so the CPU platform
+# is pinned before JAX is imported
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 

@@ -10,6 +10,12 @@ import jax
 import numpy as np
 
 
+def _auto(axes):
+    """Auto axis types: the sharding rules place arrays with
+    ``with_sharding_constraint``, which explicit axes refuse."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (one v5e pod's worth of chips) or 2x16x16 (two pods).
 
@@ -24,7 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices, have {len(devs)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, _auto(axes), devices=devs[:n])
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 1):
@@ -32,4 +38,4 @@ def make_host_mesh(data: int = 2, model: int = 2, pod: int = 1):
     shape = (pod, data, model) if pod > 1 else (data, model)
     axes = ("pod", "data", "model") if pod > 1 else ("data", "model")
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, _auto(axes), devices=jax.devices()[:n])

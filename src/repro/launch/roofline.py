@@ -4,7 +4,7 @@ Per (arch x shape) on the single-pod mesh:
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s
   memory term     = HLO_bytes_per_device / HBM_bw
   collective term = collective_bytes_per_device / (links x link_bw)
-with v5e constants from repro.config.HW. HLO_FLOPs come from the
+with the v5e peaks from ``repro.config.hw_peaks``. HLO_FLOPs come from the
 loop-trip-scaled HLO parser (hlo_stats); HLO_bytes from cost_analysis
 scaled by the same trip ratio; collective bytes from the parser.
 
@@ -18,9 +18,12 @@ import json
 import os
 from typing import Dict, List, Optional
 
-from repro.config import HW, SHAPES, ModelConfig, ShapeConfig
+from repro.config import SHAPES, ModelConfig, ShapeConfig, hw_peaks
 from repro.configs import get_config
 
+# the dry-run compiles for a pod of v5e chips on forced host devices, so
+# the chip it models is named here rather than read from the host
+TARGET_KIND = "TPU v5 lite"
 # a v5e chip has 4 usable ICI links on a 2D torus; collective traffic is
 # reported per device, so the effective egress bandwidth is links x bw.
 ICI_LINKS = 4
@@ -71,9 +74,10 @@ def roofline_row(rec: dict) -> Optional[dict]:
     bytes_dev = rec.get("bytes_per_device",
                         rec.get("bytes_accessed_scaled", 0.0))
     coll_dev = sum(rec["collective_bytes_per_device"].values())
-    t_comp = flops_dev / HW["peak_flops_bf16"]
-    t_mem = bytes_dev / HW["hbm_bw"]
-    t_coll = coll_dev / (ICI_LINKS * HW["ici_bw"])
+    hw = hw_peaks(TARGET_KIND)
+    t_comp = flops_dev / hw["peak_flops_bf16"]
+    t_mem = bytes_dev / hw["hbm_bw"]
+    t_coll = coll_dev / (ICI_LINKS * hw["ici_bw"])
     dom = max((t_comp, "compute"), (t_mem, "memory"), (t_coll, "collective"))
     mf = model_flops(cfg, shape)
     hlo_global = flops_dev * rec["devices"]
@@ -86,7 +90,7 @@ def roofline_row(rec: dict) -> Optional[dict]:
         "useful_ratio": mf / hlo_global if hlo_global else 0.0,
         # roofline fraction: useful work rate vs peak if the dominant term
         # were fully utilized
-        "roofline_fraction": (mf / rec["devices"] / HW["peak_flops_bf16"]) /
+        "roofline_fraction": (mf / rec["devices"] / hw["peak_flops_bf16"]) /
                              max(dom[0], 1e-30),
         "collectives": rec["collective_bytes_per_device"],
         "memory_gib": ((rec["memory"]["temp_bytes"] +

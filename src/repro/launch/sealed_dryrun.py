@@ -25,6 +25,9 @@ leaf), so the whole pipeline works from ShapeDtypeStructs — no 2.5B-param
 allocation.
 """
 import os
+# a host-device compile tool: it must never take a TPU, so the CPU platform
+# is pinned before JAX is imported
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
@@ -41,6 +44,7 @@ from repro.config import SHAPES, SealConfig
 from repro.configs import get_config, get_reduced
 from repro.core import cipher as C
 from repro.core import coloe as CL
+from repro.core import engine as E
 from repro.core import plan as PL
 from repro.core import sealed_store as SS
 from repro.core.sealed_tensor import SealMeta, SealedTensor
@@ -74,10 +78,11 @@ def synthetic_masks(pspec, seal: SealConfig):
     return plans
 
 
-def sealed_decode_variant(arch: str, shape_name: str, variant: str,
-                          ratio: float = 0.5, multi_pod: bool = False,
-                          reduced: bool = False):
-    """Lower+compile one sealed-decode variant; return parser stats."""
+def lower_sealed_decode(arch: str, shape_name: str, variant: str,
+                        ratio: float = 0.5, multi_pod: bool = False,
+                        reduced: bool = False):
+    """Trace and lower one sealed-decode variant's step. Returns the
+    lowered step and the record of what it stores and decrypts."""
     known = ("baseline", "counter", "coloe", "coloe_se", "coloe_fused")
     if variant not in known:
         raise ValueError(f"unknown variant {variant!r}; known: {known}")
@@ -115,7 +120,10 @@ def sealed_decode_variant(arch: str, shape_name: str, variant: str,
             # tile-sealed SealedTensor leaf: ciphertext payload in the
             # weight's own shape (sharded exactly like the plaintext param
             # would be), SE row mask, per-slice write counters, key words.
-            nb, nk, n_out, k, n, bk, bn = geom
+            # A fused leaf reaches its matmul still sealed; any other is
+            # decrypted in-graph, as ``SS.fused_params`` does, and pays its
+            # plaintext bytes every step.
+            nb, nk, n_out, k, n, bk, bn, fused = geom
             lead = leaf.shape[:nb]
             d = {"ct": jax.ShapeDtypeStruct(leaf.shape, jnp.uint32),
                  "mask": jax.ShapeDtypeStruct(lead + (k,), jnp.bool_),
@@ -131,11 +139,13 @@ def sealed_decode_variant(arch: str, shape_name: str, variant: str,
                 scheme="coloe", layout="tiles",
                 dtype=str(jnp.dtype(leaf.dtype)),
                 nonce=SS._nonce3(path), shape=tuple(leaf.shape),
-                n_batch=nb, k_ndim=nk, n_out=n_out, bk=bk, bn=bn)
+                n_batch=nb, k_ndim=nk, n_out=n_out, bk=bk, bn=bn,
+                fused=fused)
             # tile layout: no per-line counter area, SE mask rides as 1B/row
             stored_leaf = leaf.size * 4 + int(np.prod(lead + (k,)))
+            pt_leaf = 0 if fused else leaf.size * jnp.dtype(leaf.dtype).itemsize
             meta[path] = (leaf.shape, leaf.dtype, lines, lines,
-                          stored_leaf, 0)
+                          stored_leaf, pt_leaf)
             continue
         if variant == "baseline":
             enc_lines, plain_lines, streams = 0, lines, 1
@@ -174,6 +184,7 @@ def sealed_decode_variant(arch: str, shape_name: str, variant: str,
                       stored_leaf, pt_leaf)
 
     key_words = jnp.asarray(KEYW)
+    tile_eng = E.make_engine("coloe", KEYW.tobytes())
 
     def unseal(buffers):
         leaves = []
@@ -181,9 +192,10 @@ def sealed_decode_variant(arch: str, shape_name: str, variant: str,
             path = "/".join(PL._path_tuple(kp))
             if path in tile_metas:
                 b = buffers[path]
-                leaves.append(SealedTensor(b["ct"], None, b["mask"],
-                                           b["key"], b["wc"],
-                                           tile_metas[path]))
+                st = SealedTensor(b["ct"], None, b["mask"], b["key"],
+                                  b["wc"], tile_metas[path])
+                leaves.append(st if st.meta.fused
+                              else SS._unseal_tensor(tile_eng, st))
                 continue
             shape_, dtype_, lines, enc_lines = meta[path][:4]
             parts = []
@@ -228,29 +240,38 @@ def sealed_decode_variant(arch: str, shape_name: str, variant: str,
     kv_bytes = sum(int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
                    for s in jax.tree.leaves(specs["cache"]))
 
-    t0 = time.time()
     with use_mesh(mesh, rules.arch_rules(cfg, mesh)):
         jf = jax.jit(step, in_shardings=(buf_shard, c_sh, b_sh,
                                          NamedSharding(mesh, P())),
                      donate_argnums=(1,))
         lowered = jf.lower(buf_specs, specs["cache"], specs["batch"],
                            specs["pos"])
-        compiled = lowered.compile()
-    txt = compiled.as_text()
-    stats = hlo_stats.module_totals(txt)
-    ma = compiled.memory_analysis()
-    stored = sum(m[4] for m in meta.values())
-    return {
+    return lowered, {
         "arch": arch, "shape": shape_name, "variant": variant, "ratio": ratio,
+        "stored_param_bytes_global": sum(m[4] for m in meta.values()),
+        "plaintext_bytes_materialized_per_step": sum(m[5] for m in
+                                                     meta.values()),
+        "kv_cache_plaintext_bytes_per_step": kv_bytes,
+        "fused_matmul_leaves": sum(m.fused for m in tile_metas.values()),
+    }
+
+
+def sealed_decode_variant(arch: str, shape_name: str, variant: str,
+                          ratio: float = 0.5, multi_pod: bool = False,
+                          reduced: bool = False):
+    """Lower+compile one sealed-decode variant; return parser stats."""
+    t0 = time.time()
+    lowered, rec = lower_sealed_decode(arch, shape_name, variant, ratio,
+                                       multi_pod, reduced)
+    compiled = lowered.compile()
+    stats = hlo_stats.module_totals(compiled.as_text())
+    ma = compiled.memory_analysis()
+    return {
+        **rec,
         "compile_s": round(time.time() - t0, 1),
         "flops_per_device": stats["flops"],
         "bytes_per_device": stats["bytes"],
         "collective_bytes_per_device": sum(stats["collectives"].values()),
-        "stored_param_bytes_global": stored,
-        "plaintext_bytes_materialized_per_step": sum(m[5] for m in
-                                                     meta.values()),
-        "kv_cache_plaintext_bytes_per_step": kv_bytes,
-        "fused_matmul_leaves": len(tile_metas),
         "temp_gib": ma.temp_size_in_bytes / 2**30,
         "arg_gib": ma.argument_size_in_bytes / 2**30,
     }

@@ -17,6 +17,7 @@ request completed (the CI serve-smoke job runs with it).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -27,6 +28,7 @@ from repro.config import SealConfig
 from repro.configs import get_config, get_reduced
 from repro.core.security.tamper import TamperInjector
 from repro.models import transformer as T
+from repro.runtime import compile_cache
 from repro.serve.engine import GroupServeEngine, ServeEngine
 
 
@@ -70,7 +72,16 @@ def drive(eng, prompts, arrivals, submit_kw) -> list:
     return reqs
 
 
-def main():
+@dataclasses.dataclass
+class ServeRun:
+    """What one launcher run produced: ``ok`` is the exit verdict of the
+    checks that were asked for."""
+    ok: bool
+    requests: list
+    stats: dict
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2_1_8b")
     ap.add_argument("--production", action="store_true")
@@ -119,10 +130,13 @@ def main():
                          "many scheduler steps (0: unbounded)")
     ap.add_argument("--check", action="store_true",
                     help="exit nonzero unless every request completed")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args) -> ServeRun:
+    """Build the engine the arguments describe, drive the request trace
+    through it, print the summary and run the requested checks."""
     cfg = get_config(args.arch) if args.production else get_reduced(args.arch)
-    params = T.init_params(cfg, jax.random.key(0))
     seal = None if args.seal == "none" else SealConfig(
         mode=args.seal, smart_ratio=args.smart_ratio)
     engine = args.engine
@@ -144,9 +158,13 @@ def main():
                  for i, k in enumerate(kinds)]
 
     def build(seal_cache_override=None):
+        # each engine gets its own params, made from the seed, and owns
+        # them: sealing deletes the plaintext leaf by leaf
+        params = T.init_params(cfg, jax.random.key(0))
         if engine != "continuous":
             return GroupServeEngine(cfg, params, batch_slots=args.slots,
-                                    max_len=max_len, seal=seal)
+                                    max_len=max_len, seal=seal,
+                                    donate_params=True)
         seal_cache = {"auto": None, "on": True, "off": False}[args.seal_cache]
         if seal_cache_override is not None:
             seal_cache = seal_cache_override
@@ -160,7 +178,8 @@ def main():
                            prefix_share=args.prefix_share,
                            chunk_tokens=args.chunk_tokens or None,
                            verify=verify, fault_hooks=injectors,
-                           max_run_steps=args.max_run_steps or None)
+                           max_run_steps=args.max_run_steps or None,
+                           donate_params=True)
 
     eng = build()
     if engine == "continuous":
@@ -233,7 +252,12 @@ def main():
         else:
             which = "sealed" if other.seal_cache else "plaintext"
             print(f"  replay with {which} cache: token streams bit-identical")
-    if not ok:
+    return ServeRun(ok, reqs, dict(eng.stats))
+
+
+def main():
+    compile_cache.enable()
+    if not run(parse_args()).ok:
         sys.exit(1)
 
 

@@ -13,6 +13,7 @@ import jax
 from repro.config import SealConfig, TrainConfig
 from repro.configs import get_config, get_reduced
 from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.runtime import compile_cache
 from repro.runtime.fault import Heartbeat, StepWatchdog
 from repro.train.loop import train
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--log", default=None)
     ap.add_argument("--heartbeat-dir", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch) if args.production else get_reduced(args.arch)
     tc = TrainConfig(learning_rate=args.lr, total_steps=args.steps,

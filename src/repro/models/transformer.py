@@ -24,7 +24,11 @@ from repro.sharding.api import constrain
 # init
 # --------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key):
+    """Random parameters from ``key``. Compiled, so the layer stacks are
+    written in place: built eagerly, the per-layer arrays and their stack
+    would briefly hold the whole model twice."""
     n = cfg.n_superblocks()
     ke, kh, kf, kb = jax.random.split(key, 4)
     params = {
@@ -58,7 +62,8 @@ def _embed(cfg: ModelConfig, params, batch):
     if cfg.frontend is not None:
         x = batch["embeds"].astype(dt)
     else:
-        x = jnp.take(params["embed"]["w"].astype(dt), batch["tokens"], axis=0)
+        # gather, then cast: casting the table first copies all of it
+        x = jnp.take(params["embed"]["w"], batch["tokens"], axis=0).astype(dt)
     return constrain(x, "batch", None, None)
 
 

@@ -44,6 +44,7 @@ def rescale(cfg: ModelConfig, ckpt: CheckpointManager, devices=None,
         cands = [c for c in cands if c[1] == model_axis] or cands
     data, model = cands[0]
     mesh = jax.make_mesh((data, model), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2,
                          devices=devices[:data * model])
 
     step, host = ckpt.restore()

@@ -87,7 +87,12 @@ class ServeEngine:
                  prefix_share: bool = False,
                  chunk_tokens: Optional[int] = None,
                  verify: bool = False, watchdog=None,
-                 max_run_steps: Optional[int] = None, fault_hooks=()):
+                 max_run_steps: Optional[int] = None, fault_hooks=(),
+                 donate_params: bool = False):
+        """``donate_params``: the engine owns ``params``; with sealed
+        weights each plaintext leaf is deleted once sealed, so a full-width
+        model fits beside its ciphertext and no plaintext copy stays in
+        device memory. Off when the caller reuses ``params``."""
         assert cfg.frontend is None, "serving demo targets token archs"
         bad = [k for k in cfg.pattern if k not in ("attn", "local_attn")]
         if bad:
@@ -114,7 +119,8 @@ class ServeEngine:
         self.fault_hooks = tuple(fault_hooks)
 
         if weights_sealed:
-            self.sealed = SS.seal_params(params, seal, key_bytes)
+            self.sealed = SS.seal_params(params, seal, key_bytes,
+                                         consume=donate_params)
             meta = self.sealed
 
             def _materialize(tensors):
@@ -572,14 +578,17 @@ class GroupServeEngine:
 
     def __init__(self, cfg: ModelConfig, params, *, batch_slots: int = 4,
                  max_len: int = 256, seal: Optional[SealConfig] = None,
-                 key_bytes: bytes = bytes(range(32))):
+                 key_bytes: bytes = bytes(range(32)),
+                 donate_params: bool = False):
+        """``donate_params``: as for ``ServeEngine``."""
         assert cfg.frontend is None, "serving demo targets token archs"
         self.cfg = cfg
         self.slots = batch_slots
         self.max_len = max_len
         self.seal = seal
         if seal is not None and seal.mode != "none":
-            self.sealed = SS.seal_params(params, seal, key_bytes)
+            self.sealed = SS.seal_params(params, seal, key_bytes,
+                                         consume=donate_params)
             meta = self.sealed
 
             def _materialize(tensors):

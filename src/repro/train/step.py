@@ -35,8 +35,12 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig):
 
         if n > 1:
             def split(x):
+                # microbatch i takes rows i, i+n, ...: the (data-sharded)
+                # batch axis stays the minor one and the scanned axis is
+                # unsharded, which lax.scan requires of its xs
                 b = x.shape[0]
-                return x.reshape((n, b // n) + x.shape[1:])
+                return jnp.swapaxes(
+                    x.reshape((b // n, n) + x.shape[1:]), 0, 1)
             micro = jax.tree.map(split, batch)
 
             def body(acc, mb):

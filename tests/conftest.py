@@ -4,5 +4,3 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# test-local helpers (e.g. the hypothesis fallback shim)
-sys.path.insert(0, os.path.dirname(__file__))

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 import repro.models.layers as L
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ops import flash_attention
 
 
 @pytest.mark.parametrize("b,s,hq,hkv,dh,win,cap,bq,bkv", [

@@ -103,7 +103,8 @@ from repro.sharding.api import use_mesh
 from repro.train.step import make_train_step
 
 cfg = get_reduced("qwen3_moe_30b_a3b").with_(num_layers=4)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     (jax.sharding.AxisType.Auto,) * 2)
 tc = TrainConfig(microbatches=2, remat="full")
 step = make_train_step(cfg, tc)
 pspec = T.param_spec(cfg)
@@ -127,3 +128,38 @@ print(json.dumps({{"ok": True, "temp": ma.temp_size_in_bytes,
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["ok"] and rec["flops"] > 0
+
+
+def test_sealed_dryrun_fused_variant_lowers():
+    """The coloe_fused dry-run step traces on the production mesh (its own
+    subprocess forces the host devices), and it reports what the sealed
+    store itself reports: the same fused leaves, and the same per-step
+    plaintext bytes, the tile-stored embedding included."""
+    code = f"""
+import sys
+sys.path.insert(0, {SRC!r})
+import json
+from repro.launch import sealed_dryrun as SD
+_, rec = SD.lower_sealed_decode("internlm2_1_8b", "decode_32k",
+                                "coloe_fused", reduced=True)
+print(json.dumps(rec))
+"""
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+
+    from repro.config import SealConfig
+    from repro.configs import get_reduced
+    from repro.core import sealed_store as SS
+    from repro.models import transformer as T
+    cfg = get_reduced("internlm2_1_8b")
+    params = T.init_params(cfg, jax.random.key(0))
+    sp = SS.seal_params(params, SealConfig(mode="coloe", smart_ratio=0.5),
+                        bytes(range(32)))
+    assert sp.tensors["embed/w"].meta.layout == "tiles"
+    assert "embed/w" not in sp.fused_paths()
+    assert rec["fused_matmul_leaves"] == len(sp.fused_paths()) > 0
+    assert (rec["plaintext_bytes_materialized_per_step"]
+            == sp.plaintext_bytes_materialized()
+            >= sp.tensors["embed/w"].logical_bytes())

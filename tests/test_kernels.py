@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # container has no hypothesis; deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 
@@ -49,6 +46,21 @@ def test_sealed_matmul_shapes(m, k, n, bm, bk, bn):
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_plain),
                                rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("k,n,bk,bn,wc", [(32, 64, 16, 32, 0),
+                                          (128, 256, 64, 128, 3)])
+def test_tile_pad_matches_per_word_derivation(k, n, bk, bn, wc):
+    """The pad the row-of-tiles walk XORs in equals the per-word statement
+    of the format."""
+    ctr, lane = ref.tile_counters(k, n, bk, bn, wc)
+    ks = ref.chacha20_keystream_ref(KEYW, NONCE,
+                                    jnp.arange(k * n // 16 * (wc + 1),
+                                               dtype=jnp.uint32))
+    want = np.asarray(ks)[lane, ctr]
+    got = np.asarray(ref.tile_xor(jnp.zeros((k, n), jnp.uint32), KEYW,
+                                  NONCE, bk, bn, write_counter=wc))
+    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("ratio", [0.0, 0.25, 0.5, 1.0])

@@ -130,3 +130,12 @@ def test_fig11_pool_more_bandwidth_bound_than_conv():
     pool = PM.vgg_pool_layers()[0]
     conv = PM.vgg_conv_layers()[256]
     assert PM.relative_ipc([pool], "direct") < PM.relative_ipc([conv], "direct")
+
+
+def test_hw_peaks_keyed_by_device_kind():
+    """The chip peaks are looked up by ``device_kind``; a chip without
+    published peaks is an error, never a default."""
+    from repro.config import hw_peaks
+    assert hw_peaks("TPU v5 lite")["peak_flops_bf16"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        hw_peaks("cpu")

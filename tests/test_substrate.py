@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # container has no hypothesis; deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.manager import CheckpointManager, rebuild_tree
 from repro.config import SealConfig, TrainConfig
@@ -81,7 +78,7 @@ def test_error_feedback_accumulates():
 
 
 def test_allreduce_compressed_shard_map():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     devs = jax.devices()
     mesh = Mesh(np.array(devs[:1]), ("pod",))
@@ -183,6 +180,28 @@ def test_elastic_rescale(tmp_path):
 
 
 # ---------------- fault tolerance ----------------
+
+@pytest.mark.parametrize("env", ["set", "unset"])
+def test_compile_cache_dir(env, monkeypatch):
+    """The entry points' cache is $JAX_COMPILATION_CACHE_DIR when set, and
+    otherwise the fixed ``.jax_cache`` at the root of the checkout."""
+    from repro.runtime import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    if env == "set":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable()
+        assert got == jax.config.jax_compilation_cache_dir
+        assert got == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
 
 def test_heartbeat_detects_dead_host(tmp_path):
     hb1 = Heartbeat(str(tmp_path), "h1", timeout=0.5)
