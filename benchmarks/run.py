@@ -1,7 +1,4 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived`` CSV.
-# ``--serve`` instead runs the continuous-batching serve benchmark and
-# writes BENCH_serve.json (tokens/s, p50/p99 latency, plaintext bytes).
-import argparse
 import os
 import sys
 
@@ -11,19 +8,6 @@ from benchmarks import figures as F
 
 
 def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--serve", action="store_true",
-                    help="run the serve benchmark -> BENCH_serve.json")
-    ap.add_argument("--slots", default="",
-                    help="comma list for the serve slots sweep, e.g. "
-                         "16,64,256 (with --serve)")
-    args = ap.parse_args()
-    if args.serve:
-        from benchmarks import serve
-        sweep = (tuple(int(s) for s in args.slots.split(","))
-                 if args.slots else None)
-        serve.main(sweep_slots=sweep)
-        return
     suites = [
         F.fig3a_gemm_ipc,
         F.fig10_conv_ipc,

@@ -316,8 +316,9 @@ def fused_params(sp: SealedParams, key_bytes: bytes):
     ``unseal_params``.)"""
     eng = E.make_engine(sp.seal.mode, key_bytes)
     fused = set(sp.fused_paths())
-    flat = [sp.tensors[p] if p in fused
-            else _unseal_tensor(eng, sp.tensors[p]) for p in sp.plans]
+    with jax.named_scope("weight_decrypt"):
+        flat = [sp.tensors[p] if p in fused
+                else _unseal_tensor(eng, sp.tensors[p]) for p in sp.plans]
     return jax.tree_util.tree_unflatten(sp.treedef, flat)
 
 

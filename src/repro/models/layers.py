@@ -331,22 +331,26 @@ def attention_apply(cfg: ModelConfig, p, x, positions, *, window: int,
         k = apply_rope(k, positions, cfg.rope_theta)
         k_positions = positions
         if impl == "blockwise":
-            out = blockwise_attention(q, k, v, positions, k_positions, window,
-                                      cfg.attn_softcap, scale)
+            with jax.named_scope("attention"):
+                out = blockwise_attention(q, k, v, positions, k_positions,
+                                          window, cfg.attn_softcap, scale)
         else:
             mask = _attn_mask(positions, k_positions, window)
             # bound score memory when the head axis cannot shard
             hs = logical_spec("heads")
             heads_unsharded = hs is None or hs[0] is None
             qc = 512 if (heads_unsharded and x.shape[1] >= 4096) else 0
-            out = _sdpa(q, k, v, mask, cfg.attn_softcap, scale, q_chunk=qc)
+            with jax.named_scope("attention"):
+                out = _sdpa(q, k, v, mask, cfg.attn_softcap, scale,
+                            q_chunk=qc)
         kv = (k, v)
     else:
         k, v, k_positions = kv_override
         q = apply_rope(q, positions, cfg.rope_theta)
         mask = _attn_mask(positions, k_positions, window)
-        out = _sdpa(q, k, v, mask, cfg.attn_softcap, scale,
-                    constrain_heads=False)
+        with jax.named_scope("attention"):
+            out = _sdpa(q, k, v, mask, cfg.attn_softcap, scale,
+                        constrain_heads=False)
         kv = (k, v)
     y = dense(out, p["wo"], "bshk,hkd->bsd", dt)
     y = constrain(y, "batch", None, None)

@@ -64,40 +64,46 @@ def _dense_view(cfg: ModelConfig, seal: Optional[CacheSeal], pool_j,
     the zeroed tail of this view at their absolute positions — entry j is a
     real key for j < pos_len even though only j < lengths came from the pool.
     """
-    b, mb = tables.shape
-    wpb = pool_j["k"].shape[-1]
-    wpt = MC.kv_words_per_token(cfg)
-    bs = wpb // wpt
-    seq = mb * bs
-    kw = pool_j["k"][tables]                       # (B, MB, wpb)
-    vw = pool_j["v"][tables]
-    ok = jnp.ones((b,), bool)
-    if seal is not None:
-        wcb = wc[tables]
-        if seal.mac is not None:
-            tk = seal.mac.tags(kw, tables, wcb, pool_j["lid"],
-                               tweak=seal.nonce_k)
-            tv = seal.mac.tags(vw, tables, wcb, pool_j["lid"],
-                               tweak=seal.nonce_v)
-            resident = (jnp.arange(mb, dtype=jnp.int32)[None, :]
-                        < ((lengths + bs - 1) // bs)[:, None])    # (B, MB)
-            okb = ((tk == pool_j["mac_k"][tables])
-                   & (tv == pool_j["mac_v"][tables]))
-            ok = jnp.all((~resident) | okb, axis=1)
-        kw = kw ^ KR.cache_block_otp(seal.key_words, seal.nonce_k, tables,
-                                     wcb, pool_j["lid"], wpb)
-        vw = vw ^ KR.cache_block_otp(seal.key_words, seal.nonce_v, tables,
-                                     wcb, pool_j["lid"], wpb)
-    dt = jnp.dtype(cfg.dtype)
-    k = MC.words_to_kv(kw, dt).reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
-    v = MC.words_to_kv(vw, dt).reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
-    pos = jnp.arange(seq, dtype=jnp.int32)[None, :]
-    valid = pos < lengths[:, None]                 # (B, L)
-    k = jnp.where(valid[..., None, None], k, 0)
-    v = jnp.where(valid[..., None, None], v, 0)
-    vpos = valid if pos_len is None else pos < pos_len[:, None]
-    pos = jnp.where(vpos, pos, MC.INVALID_POS)
-    return {"k": k, "v": v, "pos": pos}, ok
+    with jax.named_scope("kv_view"):
+        b, mb = tables.shape
+        wpb = pool_j["k"].shape[-1]
+        wpt = MC.kv_words_per_token(cfg)
+        bs = wpb // wpt
+        seq = mb * bs
+        with jax.named_scope("kv_gather"):
+            kw = pool_j["k"][tables]               # (B, MB, wpb)
+            vw = pool_j["v"][tables]
+            wcb = wc[tables] if seal is not None else None
+        ok = jnp.ones((b,), bool)
+        if seal is not None:
+            if seal.mac is not None:
+                with jax.named_scope("kv_mac"):
+                    tk = seal.mac.tags(kw, tables, wcb, pool_j["lid"],
+                                       tweak=seal.nonce_k)
+                    tv = seal.mac.tags(vw, tables, wcb, pool_j["lid"],
+                                       tweak=seal.nonce_v)
+                    resident = (jnp.arange(mb, dtype=jnp.int32)[None, :]
+                                < ((lengths + bs - 1) // bs)[:, None])
+                    okb = ((tk == pool_j["mac_k"][tables])
+                           & (tv == pool_j["mac_v"][tables]))
+                    ok = jnp.all((~resident) | okb, axis=1)
+            with jax.named_scope("kv_unseal"):
+                kw = kw ^ KR.cache_block_otp(seal.key_words, seal.nonce_k,
+                                             tables, wcb, pool_j["lid"], wpb)
+                vw = vw ^ KR.cache_block_otp(seal.key_words, seal.nonce_v,
+                                             tables, wcb, pool_j["lid"], wpb)
+        with jax.named_scope("kv_mask"):
+            dt = jnp.dtype(cfg.dtype)
+            shape = (b, seq, cfg.num_kv_heads, cfg.head_dim)
+            k = MC.words_to_kv(kw, dt).reshape(shape)
+            v = MC.words_to_kv(vw, dt).reshape(shape)
+            pos = jnp.arange(seq, dtype=jnp.int32)[None, :]
+            valid = pos < lengths[:, None]             # (B, L)
+            k = jnp.where(valid[..., None, None], k, 0)
+            v = jnp.where(valid[..., None, None], v, 0)
+            vpos = valid if pos_len is None else pos < pos_len[:, None]
+            pos = jnp.where(vpos, pos, MC.INVALID_POS)
+        return {"k": k, "v": v, "pos": pos}, ok
 
 
 def decode_logits(cfg: ModelConfig, params, pools, tables, lengths, wc,
@@ -186,73 +192,76 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
     are scattered with dropped (out-of-bounds) indices, so masked slots
     cost no writes and no counter bumps. Returns (pools, wc).
     """
-    wpt = MC.kv_words_per_token(cfg)
-    b, mb = tables.shape
-    nb = wc.shape[0]
-    new_pools = []
-    wc_out = wc
-    for j in range(len(cfg.pattern)):
-        pj, uj = pools[j], updates[j]
-        wpb = pj["k"].shape[-1]
-        bs = wpb // wpt
-        c = uj["k_new"].shape[2]
-        nspan = 1 + (c + bs - 2) // bs         # blocks a chunk write can span
-        lid = pj["lid"]
-        n = lid.shape[0]
-        o = lengths % bs                                         # (B,)
-        span = (lengths // bs)[:, None] + jnp.arange(nspan)[None, :]
-        span = jnp.minimum(span, mb - 1)
-        pb = jnp.take_along_axis(tables, span, axis=1)           # (B, nspan)
-        s_id = jnp.arange(nspan)[None, :]
-        touched = ((s_id * bs < (o + counts)[:, None])
-                   & ((s_id + 1) * bs > o[:, None])
-                   & (counts > 0)[:, None])                      # (B, nspan)
-        w2 = nspan * wpb
-        widx = jnp.arange(w2)
-        tok_of_w = widx // wpt                                   # window token
-        sel = ((tok_of_w[None, :] >= o[:, None])
-               & (tok_of_w[None, :] < (o + counts)[:, None]))    # (B, w2)
-        roll = (widx[None, :] - (o * wpt)[:, None]) % w2         # (B, w2)
+    with jax.named_scope("kv_append"):
+        wpt = MC.kv_words_per_token(cfg)
+        b, mb = tables.shape
+        nb = wc.shape[0]
+        new_pools = []
+        wc_out = wc
+        for j in range(len(cfg.pattern)):
+            pj, uj = pools[j], updates[j]
+            wpb = pj["k"].shape[-1]
+            bs = wpb // wpt
+            c = uj["k_new"].shape[2]
+            nspan = 1 + (c + bs - 2) // bs     # blocks a chunk write can span
+            lid = pj["lid"]
+            n = lid.shape[0]
+            o = lengths % bs                                     # (B,)
+            span = (lengths // bs)[:, None] + jnp.arange(nspan)[None, :]
+            span = jnp.minimum(span, mb - 1)
+            pb = jnp.take_along_axis(tables, span, axis=1)       # (B, nspan)
+            s_id = jnp.arange(nspan)[None, :]
+            touched = ((s_id * bs < (o + counts)[:, None])
+                       & ((s_id + 1) * bs > o[:, None])
+                       & (counts > 0)[:, None])                  # (B, nspan)
+            w2 = nspan * wpb
+            widx = jnp.arange(w2)
+            tok_of_w = widx // wpt                               # window token
+            sel = ((tok_of_w[None, :] >= o[:, None])
+                   & (tok_of_w[None, :] < (o + counts)[:, None]))  # (B, w2)
+            roll = (widx[None, :] - (o * wpt)[:, None]) % w2     # (B, w2)
 
-        def splice(pool_words, mac_words, x_new, nonce):
-            tw = MC.kv_to_words(x_new.reshape(n, b, c, -1))      # (n,B,C,wpt)
-            base = jnp.concatenate(
-                [tw.reshape(n, b, c * wpt),
-                 jnp.zeros((n, b, w2 - c * wpt), jnp.uint32)], axis=-1)
-            rolled = jnp.take_along_axis(
-                base, jnp.broadcast_to(roll[None], (n, b, w2)), axis=-1)
-            blk = pool_words[:, pb]                              # (n,B,ns,wpb)
-            flat = blk.reshape(n, b, w2)
-            if seal is not None:
-                otp0 = KR.cache_block_otp(seal.key_words, nonce, pb, wc[pb],
-                                          lid[:, None, None], wpb)
-                otp1 = KR.cache_block_otp(seal.key_words, nonce, pb,
-                                          wc[pb] + 1, lid[:, None, None], wpb)
-                flat = flat ^ otp0.reshape(n, b, w2)
-            out = jnp.where(sel[None], rolled, flat)
-            if seal is not None:
-                out = out ^ otp1.reshape(n, b, w2)
-            out = out.reshape(n, b, nspan, wpb)
-            out = jnp.where(touched[None, :, :, None], out, blk)
-            tgt = jnp.where(touched, pb, nb)       # untouched -> dropped
-            if seal is not None and seal.mac is not None:
-                # re-MAC the rewritten image under the bumped counter —
-                # tags of untouched rows land on dropped indices
-                tags = seal.mac.tags(out, pb, wc[pb] + 1,
-                                     lid[:, None, None], tweak=nonce)
-                mac_words = mac_words.at[:, tgt].set(tags, mode="drop")
-            return pool_words.at[:, tgt].set(out, mode="drop"), mac_words
+            def splice(pool_words, mac_words, x_new, nonce):
+                tw = MC.kv_to_words(x_new.reshape(n, b, c, -1))  # (n,B,C,wpt)
+                base = jnp.concatenate(
+                    [tw.reshape(n, b, c * wpt),
+                     jnp.zeros((n, b, w2 - c * wpt), jnp.uint32)], axis=-1)
+                rolled = jnp.take_along_axis(
+                    base, jnp.broadcast_to(roll[None], (n, b, w2)), axis=-1)
+                blk = pool_words[:, pb]                          # (n,B,ns,wpb)
+                flat = blk.reshape(n, b, w2)
+                if seal is not None:
+                    otp0 = KR.cache_block_otp(seal.key_words, nonce, pb,
+                                              wc[pb], lid[:, None, None],
+                                              wpb)
+                    otp1 = KR.cache_block_otp(seal.key_words, nonce, pb,
+                                              wc[pb] + 1, lid[:, None, None],
+                                              wpb)
+                    flat = flat ^ otp0.reshape(n, b, w2)
+                out = jnp.where(sel[None], rolled, flat)
+                if seal is not None:
+                    out = out ^ otp1.reshape(n, b, w2)
+                out = out.reshape(n, b, nspan, wpb)
+                out = jnp.where(touched[None, :, :, None], out, blk)
+                tgt = jnp.where(touched, pb, nb)       # untouched -> dropped
+                if seal is not None and seal.mac is not None:
+                    # re-MAC the rewritten image under the bumped counter —
+                    # tags of untouched rows land on dropped indices
+                    tags = seal.mac.tags(out, pb, wc[pb] + 1,
+                                         lid[:, None, None], tweak=nonce)
+                    mac_words = mac_words.at[:, tgt].set(tags, mode="drop")
+                return pool_words.at[:, tgt].set(out, mode="drop"), mac_words
 
-        nk, nmk = splice(pj["k"], pj["mac_k"], uj["k_new"],
-                         seal.nonce_k if seal is not None else None)
-        nv, nmv = splice(pj["v"], pj["mac_v"], uj["v_new"],
-                         seal.nonce_v if seal is not None else None)
-        new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                          "lid": lid})
-        if j == 0:
-            tgt = jnp.where(touched, pb, nb)
-            wc_out = wc.at[tgt].add(jnp.uint32(1), mode="drop")
-    return tuple(new_pools), wc_out
+            nk, nmk = splice(pj["k"], pj["mac_k"], uj["k_new"],
+                             seal.nonce_k if seal is not None else None)
+            nv, nmv = splice(pj["v"], pj["mac_v"], uj["v_new"],
+                             seal.nonce_v if seal is not None else None)
+            new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
+                              "lid": lid})
+            if j == 0:
+                tgt = jnp.where(touched, pb, nb)
+                wc_out = wc.at[tgt].add(jnp.uint32(1), mode="drop")
+        return tuple(new_pools), wc_out
 
 
 def copy_blocks(cfg: ModelConfig, seal: Optional[CacheSeal], pools, wc,
@@ -269,43 +278,45 @@ def copy_blocks(cfg: ModelConfig, seal: Optional[CacheSeal], pools, wc,
     launder a tampered block into a freshly-MACed copy) and the copy gets
     its own tag under the destination (address, counter).
     """
-    nb = wc.shape[0]
-    tgt = jnp.where(mask, dst, nb)                 # pads -> dropped
-    new_pools = []
-    oks = []
-    for pj in pools:
-        wpb = pj["k"].shape[-1]
-        lid = pj["lid"]
+    with jax.named_scope("kv_copy"):
+        nb = wc.shape[0]
+        tgt = jnp.where(mask, dst, nb)                 # pads -> dropped
+        new_pools = []
+        oks = []
+        for pj in pools:
+            wpb = pj["k"].shape[-1]
+            lid = pj["lid"]
 
-        def copy(pool_words, mac_words, nonce):
-            blk = pool_words[:, src]               # (n, K, wpb)
-            ok = jnp.bool_(True)
-            if seal is not None:
-                if seal.mac is not None:
-                    ts = seal.mac.tags(blk, src, wc[src], lid[:, None],
-                                       tweak=nonce)
-                    ok = jnp.all(~mask[None, :]
-                                 | (ts == mac_words[:, src]))
-                blk = blk ^ KR.cache_block_otp(
-                    seal.key_words, nonce, src, wc[src], lid[:, None], wpb)
-                blk = blk ^ KR.cache_block_otp(
-                    seal.key_words, nonce, dst, wc[dst] + 1,
-                    lid[:, None], wpb)
-                if seal.mac is not None:
-                    td = seal.mac.tags(blk, dst, wc[dst] + 1, lid[:, None],
-                                       tweak=nonce)
-                    mac_words = mac_words.at[:, tgt].set(td, mode="drop")
-            return pool_words.at[:, tgt].set(blk, mode="drop"), mac_words, ok
+            def copy(pool_words, mac_words, nonce):
+                blk = pool_words[:, src]               # (n, K, wpb)
+                ok = jnp.bool_(True)
+                if seal is not None:
+                    if seal.mac is not None:
+                        ts = seal.mac.tags(blk, src, wc[src], lid[:, None],
+                                           tweak=nonce)
+                        ok = jnp.all(~mask[None, :]
+                                     | (ts == mac_words[:, src]))
+                    blk = blk ^ KR.cache_block_otp(
+                        seal.key_words, nonce, src, wc[src], lid[:, None], wpb)
+                    blk = blk ^ KR.cache_block_otp(
+                        seal.key_words, nonce, dst, wc[dst] + 1,
+                        lid[:, None], wpb)
+                    if seal.mac is not None:
+                        td = seal.mac.tags(blk, dst, wc[dst] + 1, lid[:, None],
+                                           tweak=nonce)
+                        mac_words = mac_words.at[:, tgt].set(td, mode="drop")
+                return (pool_words.at[:, tgt].set(blk, mode="drop"),
+                        mac_words, ok)
 
-        nk, nmk, ok_k = copy(pj["k"], pj["mac_k"],
-                             seal.nonce_k if seal is not None else None)
-        nv, nmv, ok_v = copy(pj["v"], pj["mac_v"],
-                             seal.nonce_v if seal is not None else None)
-        new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                          "lid": lid})
-        oks.append(ok_k & ok_v)
-    return (tuple(new_pools), wc.at[tgt].add(jnp.uint32(1), mode="drop"),
-            jnp.all(jnp.stack(oks)))
+            nk, nmk, ok_k = copy(pj["k"], pj["mac_k"],
+                                 seal.nonce_k if seal is not None else None)
+            nv, nmv, ok_v = copy(pj["v"], pj["mac_v"],
+                                 seal.nonce_v if seal is not None else None)
+            new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
+                              "lid": lid})
+            oks.append(ok_k & ok_v)
+        return (tuple(new_pools), wc.at[tgt].add(jnp.uint32(1), mode="drop"),
+                jnp.all(jnp.stack(oks)))
 
 
 def apply_paged_updates(cfg: ModelConfig, seal: Optional[CacheSeal], pools,

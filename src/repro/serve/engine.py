@@ -59,6 +59,10 @@ class Request:
                                       # exhausted; None on clean completion
 
 
+# a host span on the profiler's clock; costs one object when nothing records
+_span = jax.profiler.TraceAnnotation
+
+
 def _jit(fn, donate):
     """jit with buffer donation: every transition rebinds the engine's
     ``_state``/``_pools`` to the outputs, so the inputs are dead and XLA
@@ -198,6 +202,8 @@ class ServeEngine:
             "tokens": 0, "cow_copies": 0,
             "mac_checks": 0, "mac_failures": 0, "retries": 0,
             "shared_prefix_blocks": 0, "shared_prefix_tokens": 0,
+            "kv_blocks_gathered": 0, "kv_blocks_resident": 0,
+            "kv_blocks_reserved": 0,
             "fused_matmul_leaves": (len(self.sealed.fused_paths())
                                     if self.sealed else 0),
             "weights_plaintext_bytes_per_step": w_pt,
@@ -235,19 +241,27 @@ class ServeEngine:
         prompts, advance every decoding slot one token; returns the
         requests that completed during this step. Registered fault hooks
         fire first — they model an adversary mutating the sealed memory
-        image between dispatches."""
-        n0 = len(self._done)
-        for hook in self.fault_hooks:
-            hook.on_step(self)
-        if not self._wswept:
-            self._verify_weights()
-        self._admit()
-        if any(p is not None for p in self._pending):
-            self._chunk_tick()
-        if any(r is not None and self._pending[i] is None
-               for i, r in enumerate(self._active)):
-            self._decode_tick()
-        return self._done[n0:]
+        image between dispatches.
+
+        When the profiler records, the step and each of its phases leave
+        a host span on the trace's clock: ``serve.step`` around
+        ``serve.admit``, ``serve.chunk`` and ``serve.decode`` (each with
+        a ``.readback`` child around the blocking token copy),
+        ``serve.integrity`` and ``serve.evict``."""
+        with _span("serve.step"):
+            n0 = len(self._done)
+            for hook in self.fault_hooks:
+                hook.on_step(self)
+            if not self._wswept:
+                self._verify_weights()
+            with _span("serve.admit"):
+                self._admit()
+            if any(p is not None for p in self._pending):
+                self._chunk_tick()
+            if any(r is not None and self._pending[i] is None
+                   for i, r in enumerate(self._active)):
+                self._decode_tick()
+            return self._done[n0:]
 
     def run(self, max_steps: Optional[int] = None) -> List[Request]:
         """Drain queue + in-flight work; returns the requests completed by
@@ -265,19 +279,34 @@ class ServeEngine:
         while self.busy:
             before = (len(self.queue), self.stats["decode_steps"],
                       self.stats["prefills"])
-            t0 = time.time()
+            t0 = time.perf_counter()
             self.step()
             after = (len(self.queue), self.stats["decode_steps"],
                      self.stats["prefills"])
             assert after != before, "scheduler made no progress"
             steps += 1
             if self.watchdog is not None:
-                self.watchdog.check(time.time() - t0)
+                self.watchdog.check(time.perf_counter() - t0)
             if limit is not None and steps >= limit and self.busy:
                 raise StragglerTimeout(
                     f"serve drain exceeded {limit} steps with work still "
                     f"in flight ({len(self.queue)} queued)")
         return self._done[n0:]
+
+    def compiled_hlo(self) -> Dict[str, str]:
+        """The optimised HLO text of the decode tick and the chunk step as
+        compiled for this engine's shapes (a persistent compile cache hands
+        back the very programs that ran). Each instruction's ``op_name``
+        metadata holds the scopes its operation ran in (``kv_view``,
+        ``weight_decrypt``, ...), which a device trace's operation events
+        do not carry; the keys are the programs' names in such a trace."""
+        a, c = self._admit_n, self.chunk_tokens
+        chunk = (np.zeros((a,), np.int32), np.zeros((a, c), np.int32),
+                 np.zeros((a,), np.int32), np.zeros((a,), bool))
+        return {"tick": self._decode.lower(
+                    *self._decode_args()).compile().as_text(),
+                "chunk_step": self._chunk.lower(
+                    *self._decode_args(), *chunk).compile().as_text()}
 
     def check_device_mirror(self):
         """Debug/assert view: the host mirrors must track the device
@@ -397,23 +426,25 @@ class ServeEngine:
         rows = [i for i, p in enumerate(self._pending) if p is not None][:a]
         if not rows:
             return
-        sl = np.full((a,), self.slots, np.int32)
-        toks = np.zeros((a, c), np.int32)
-        cl = np.zeros((a,), np.int32)
-        fin = np.zeros((a,), bool)
-        for i, slot in enumerate(rows):
-            pend = self._pending[slot]
-            n = min(len(pend), c)
-            sl[i] = slot
-            toks[i, :n] = pend[:n]
-            cl[i] = n
-            fin[i] = n == len(pend)
-        tok, cok, self._state, self._pools = self._chunk(
-            self._params_arg, self._pools, self._state, jnp.asarray(sl),
-            jnp.asarray(toks), jnp.asarray(cl), jnp.asarray(fin))
-        self.stats["prefills"] += 1
-        self.stats["prefill_chunks"] += len(rows)
-        tok = np.asarray(tok)
+        with _span("serve.chunk"):
+            sl = np.full((a,), self.slots, np.int32)
+            toks = np.zeros((a, c), np.int32)
+            cl = np.zeros((a,), np.int32)
+            fin = np.zeros((a,), bool)
+            for i, slot in enumerate(rows):
+                pend = self._pending[slot]
+                n = min(len(pend), c)
+                sl[i] = slot
+                toks[i, :n] = pend[:n]
+                cl[i] = n
+                fin[i] = n == len(pend)
+            tok, cok, self._state, self._pools = self._chunk(
+                self._params_arg, self._pools, self._state, jnp.asarray(sl),
+                jnp.asarray(toks), jnp.asarray(cl), jnp.asarray(fin))
+            self.stats["prefills"] += 1
+            self.stats["prefill_chunks"] += len(rows)
+            with _span("serve.chunk.readback"):
+                tok = np.asarray(tok)
         cok_h = self._check_integrity(cok, len(rows))
         finished: List[int] = []
         failed: List[int] = []
@@ -452,20 +483,38 @@ class ServeEngine:
         everything is already device-resident — params, pools, SchedState."""
         return (self._params_arg, self._pools, self._state)
 
+    def _kv_blocks(self, running: List[int]) -> Dict[str, int]:
+        """What one decode tick's paged view reads of the cache, per layer,
+        from the host mirrors: every slot's whole table
+        (``blocks_gathered``), the blocks that hold the running slots'
+        tokens (``blocks_resident``), the blocks every slot holds
+        (``blocks_reserved``), and the running slots. Added to the
+        cumulative ``kv_blocks_*`` stats."""
+        bs = self.block_size
+        kv = {"blocks_gathered": self.slots * (self.max_len // bs),
+              "blocks_resident": int(
+                  ((self._lengths[running] + bs - 1) // bs).sum()),
+              "blocks_reserved": sum(len(b) for b in self._slot_blocks),
+              "running": len(running)}
+        for k in ("gathered", "resident", "reserved"):
+            self.stats[f"kv_blocks_{k}"] += kv[f"blocks_{k}"]
+        return kv
+
     def _decode_tick(self):
-        tok, cok, self._state, self._pools = self._decode(
-            *self._decode_args())
-        self.stats["decode_steps"] += 1
-        tok = np.asarray(tok)                  # the ONLY d2h copy per tick
-        n_running = sum(1 for i, r in enumerate(self._active)
-                        if r is not None and self._pending[i] is None)
-        cok_h = self._check_integrity(cok, n_running)
+        running = [i for i, r in enumerate(self._active)
+                   if r is not None and self._pending[i] is None]
+        with _span("serve.decode", **self._kv_blocks(running)):
+            tok, cok, self._state, self._pools = self._decode(
+                *self._decode_args())
+            self.stats["decode_steps"] += 1
+            with _span("serve.decode.readback"):
+                tok = np.asarray(tok)          # the ONLY d2h copy per tick
+        cok_h = self._check_integrity(cok, len(running))
         bs = self.block_size
         finished: List[int] = []
         failed: List[int] = []
-        for slot, r in enumerate(self._active):
-            if r is None or self._pending[slot] is not None:
-                continue
+        for slot in running:
+            r = self._active[slot]
             # mirror the device's seal-on-write counter bump of the tail
             # block the new K/V token landed in — for failed slots too:
             # the mirror tracks what the dispatch did, not what we trust
@@ -500,7 +549,9 @@ class ServeEngine:
         if not (self.verify and self._has_wverify):
             return
         self.stats["mac_checks"] += 1
-        if not bool(self._wverify(self._params_arg)):
+        with _span("serve.verify_weights"):
+            intact = bool(self._wverify(self._params_arg))
+        if not intact:
             self.stats["mac_failures"] += 1
             raise SealedIntegrityError(
                 "weights", "sealed weight image failed its MAC sweep — "
@@ -514,7 +565,8 @@ class ServeEngine:
         if not self.verify:
             return None
         self.stats["mac_checks"] += n_checked
-        return np.asarray(cok)
+        with _span("serve.integrity"):
+            return np.asarray(cok)
 
     def _integrity_retry(self, slots: List[int]):
         """Graceful degradation for cache MAC failures: fail ONLY the
@@ -526,25 +578,26 @@ class ServeEngine:
         a second failure marks the request ``error="integrity"``. Slots
         that passed their check are untouched and decode bit-identically
         through the recovery."""
-        self.stats["mac_failures"] += len(slots)
-        victims = [self._active[s] for s in slots]
-        if self._registry is not None:
-            bad = [b for s in slots for b in self._slot_blocks[s]]
-            self._registry.purge_blocks(bad)
-        self._evict_slots(slots, complete=False)
-        self._state = dataclasses.replace(
-            self._state, wc=jnp.asarray(self._wc))
-        for r in reversed(victims):
-            if r.retries >= 1:
-                r.error = "integrity"
-                r.done = True
-                r.t_done = time.time()
-                self._done.append(r)
-                continue
-            r.retries += 1
-            r.out = []
-            self.stats["retries"] += 1
-            self.queue.insert(0, r)
+        with _span("serve.integrity"):
+            self.stats["mac_failures"] += len(slots)
+            victims = [self._active[s] for s in slots]
+            if self._registry is not None:
+                bad = [b for s in slots for b in self._slot_blocks[s]]
+                self._registry.purge_blocks(bad)
+            self._evict_slots(slots, complete=False)
+            self._state = dataclasses.replace(
+                self._state, wc=jnp.asarray(self._wc))
+            for r in reversed(victims):
+                if r.retries >= 1:
+                    r.error = "integrity"
+                    r.done = True
+                    r.t_done = time.time()
+                    self._done.append(r)
+                    continue
+                r.retries += 1
+                r.out = []
+                self.stats["retries"] += 1
+                self.queue.insert(0, r)
 
     def _evict_slots(self, slots: List[int], complete: bool = True):
         """Batched slot teardown: one device evict dispatch zeroes the
@@ -552,23 +605,24 @@ class ServeEngine:
         survive while the registry or another reader holds them). With
         ``complete=False`` the requests are NOT marked done — the caller
         owns their fate (integrity retry / requeue)."""
-        ids = np.full((self.slots,), self.slots, np.int32)
-        ids[:len(slots)] = slots
-        self._state = self._evict_t(self._state, jnp.asarray(ids))
-        for slot in slots:
-            r = self._active[slot]
-            if complete:
-                r.done = True
-                r.t_done = time.time()
-                self._done.append(r)
-            self._alloc.decref(self._slot_blocks[slot])
-            self._slot_blocks[slot] = []
-            self._tables[slot] = 0
-            self._lengths[slot] = 0
-            self._counts[slot] = 0
-            self._last_tok[slot] = 0
-            self._active[slot] = None
-            self._pending[slot] = None
+        with _span("serve.evict"):
+            ids = np.full((self.slots,), self.slots, np.int32)
+            ids[:len(slots)] = slots
+            self._state = self._evict_t(self._state, jnp.asarray(ids))
+            for slot in slots:
+                r = self._active[slot]
+                if complete:
+                    r.done = True
+                    r.t_done = time.time()
+                    self._done.append(r)
+                self._alloc.decref(self._slot_blocks[slot])
+                self._slot_blocks[slot] = []
+                self._tables[slot] = 0
+                self._lengths[slot] = 0
+                self._counts[slot] = 0
+                self._last_tok[slot] = 0
+                self._active[slot] = None
+                self._pending[slot] = None
 
 
 class GroupServeEngine:

@@ -44,14 +44,15 @@ def sample_logits(logits, keys, temperature, top_k, top_p):
     sampling cost, and greedy decode (the common serving default) never
     consults the sorted order.
     """
-    b, v = logits.shape
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sampling"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def full(_):
-        return _sample_full(logits, keys, temperature, top_k, top_p, greedy)
+        def full(_):
+            return _sample_full(logits, keys, temperature, top_k, top_p,
+                                greedy)
 
-    return jax.lax.cond(jnp.all(temperature <= 0),
-                        lambda _: greedy, full, operand=None)
+        return jax.lax.cond(jnp.all(temperature <= 0),
+                            lambda _: greedy, full, operand=None)
 
 
 def _sample_full(logits, keys, temperature, top_k, top_p, greedy):
