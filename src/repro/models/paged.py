@@ -186,11 +186,12 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
     for the decode append (C == 1) and the chunked prefill (C == chunk).
 
     Touched blocks are fetched, unsealed under the current write counter,
-    spliced, and re-sealed under ``wc + 1``; ``wc`` is bumped in the
-    returned array (device-resident scheduler state — the host keeps only a
-    debug mirror). Rows with counts == 0 touch nothing: untouched blocks
-    are scattered with dropped (out-of-bounds) indices, so masked slots
-    cost no writes and no counter bumps. Returns (pools, wc).
+    spliced a whole token at a time, and re-sealed under ``wc + 1``;
+    ``wc`` is bumped in the returned array (device-resident scheduler
+    state — the host keeps only a debug mirror). Rows with counts == 0
+    touch nothing: untouched blocks are scattered with dropped
+    (out-of-bounds) indices, so masked slots cost no writes and no counter
+    bumps. Returns (pools, wc).
     """
     with jax.named_scope("kv_append"):
         wpt = MC.kv_words_per_token(cfg)
@@ -214,22 +215,19 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
             touched = ((s_id * bs < (o + counts)[:, None])
                        & ((s_id + 1) * bs > o[:, None])
                        & (counts > 0)[:, None])                  # (B, nspan)
-            w2 = nspan * wpb
-            widx = jnp.arange(w2)
-            tok_of_w = widx // wpt                               # window token
-            sel = ((tok_of_w[None, :] >= o[:, None])
-                   & (tok_of_w[None, :] < (o + counts)[:, None]))  # (B, w2)
-            roll = (widx[None, :] - (o * wpt)[:, None]) % w2     # (B, w2)
+            tok = jnp.arange(nspan * bs)                         # window token
+            sel = ((tok[None, :] >= o[:, None])
+                   & (tok[None, :] < (o + counts)[:, None]))      # (B, ns*bs)
+            # the chunk token each window token takes: indices move whole
+            # tokens (wpt words), and o + c <= nspan * bs, so none wraps
+            src = (tok[None, :] - o[:, None])[None, :, :, None]  # 1,B,ns*bs,1
 
             def splice(pool_words, mac_words, x_new, nonce):
                 tw = MC.kv_to_words(x_new.reshape(n, b, c, -1))  # (n,B,C,wpt)
-                base = jnp.concatenate(
-                    [tw.reshape(n, b, c * wpt),
-                     jnp.zeros((n, b, w2 - c * wpt), jnp.uint32)], axis=-1)
-                rolled = jnp.take_along_axis(
-                    base, jnp.broadcast_to(roll[None], (n, b, w2)), axis=-1)
+                if c > 1:
+                    tw = jnp.take_along_axis(tw, src, axis=2, mode="clip")
                 blk = pool_words[:, pb]                          # (n,B,ns,wpb)
-                flat = blk.reshape(n, b, w2)
+                flat = blk.reshape(n, b, nspan * bs, wpt)
                 if seal is not None:
                     otp0 = KR.cache_block_otp(seal.key_words, nonce, pb,
                                               wc[pb], lid[:, None, None],
@@ -237,10 +235,11 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
                     otp1 = KR.cache_block_otp(seal.key_words, nonce, pb,
                                               wc[pb] + 1, lid[:, None, None],
                                               wpb)
-                    flat = flat ^ otp0.reshape(n, b, w2)
-                out = jnp.where(sel[None], rolled, flat)
+                    flat = flat ^ otp0.reshape(flat.shape)
+                # with C == 1 the one token broadcasts and sel picks offset o
+                out = jnp.where(sel[None, :, :, None], tw, flat)
                 if seal is not None:
-                    out = out ^ otp1.reshape(n, b, w2)
+                    out = out ^ otp1.reshape(flat.shape)
                 out = out.reshape(n, b, nspan, wpb)
                 out = jnp.where(touched[None, :, :, None], out, blk)
                 tgt = jnp.where(touched, pb, nb)       # untouched -> dropped
@@ -317,59 +316,6 @@ def copy_blocks(cfg: ModelConfig, seal: Optional[CacheSeal], pools, wc,
             oks.append(ok_k & ok_v)
         return (tuple(new_pools), wc.at[tgt].add(jnp.uint32(1), mode="drop"),
                 jnp.all(jnp.stack(oks)))
-
-
-def apply_paged_updates(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
-                        updates, tables, lengths, wc):
-    """Append each slot's new K/V token into its tail block (write path).
-
-    The tail block is fetched, unsealed under the current write counter,
-    the token's words are spliced in at word offset (length % bs) * wpt,
-    and the whole block is re-sealed under ``wc + 1`` — the host mirrors
-    the bump after the step. Inactive slots (length 0, zeroed table row)
-    land on the scratch block 0.
-    """
-    wpt = MC.kv_words_per_token(cfg)
-    b = tables.shape[0]
-    new_pools = []
-    for j in range(len(cfg.pattern)):
-        pj, uj = pools[j], updates[j]
-        wpb = pj["k"].shape[-1]
-        bs = wpb // wpt
-        off = lengths % bs                                     # (B,)
-        pb = tables[jnp.arange(b), lengths // bs]              # (B,)
-        lid = pj["lid"]                                        # (n,)
-        n = lid.shape[0]
-
-        def append(pool_words, mac_words, x_new, nonce):
-            tw = MC.kv_to_words(x_new[:, :, 0].reshape(n, b, -1))  # (n,B,wpt)
-            blk = pool_words[:, pb]                                # (n,B,wpb)
-            if seal is not None:
-                blk = blk ^ KR.cache_block_otp(
-                    seal.key_words, nonce, pb, wc[pb], lid[:, None], wpb)
-            base = jnp.concatenate(
-                [tw, jnp.zeros((n, b, wpb - wpt), jnp.uint32)], axis=-1)
-            idx = (jnp.arange(wpb)[None, :] - off[:, None] * wpt) % wpb
-            rolled = jnp.take_along_axis(
-                base, jnp.broadcast_to(idx[None], (n, b, wpb)), axis=-1)
-            sel = (jnp.arange(wpb)[None, :] // wpt) == off[:, None]  # (B,wpb)
-            blk = jnp.where(sel[None], rolled, blk)
-            if seal is not None:
-                blk = blk ^ KR.cache_block_otp(
-                    seal.key_words, nonce, pb, wc[pb] + 1, lid[:, None], wpb)
-                if seal.mac is not None:
-                    tags = seal.mac.tags(blk, pb, wc[pb] + 1, lid[:, None],
-                                         tweak=nonce)
-                    mac_words = mac_words.at[:, pb].set(tags)
-            return pool_words.at[:, pb].set(blk), mac_words
-
-        nk, nmk = append(pj["k"], pj["mac_k"], uj["k_new"],
-                         seal.nonce_k if seal is not None else None)
-        nv, nmv = append(pj["v"], pj["mac_v"], uj["v_new"],
-                         seal.nonce_v if seal is not None else None)
-        new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                          "lid": lid})
-    return tuple(new_pools)
 
 
 def prefill_logits(cfg: ModelConfig, params, tokens, true_len):

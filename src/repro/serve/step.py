@@ -212,28 +212,6 @@ def make_decode_step(cfg: ModelConfig):
     return decode_step
 
 
-def make_paged_decode_step(cfg: ModelConfig, materialize, cache_seal):
-    """Continuous-batching decode step over the paged (optionally sealed)
-    KV pools: every slot advances one token at its own position, new K/V
-    are appended (sealed) into each slot's tail block, and the next token
-    is sampled with each request's own PRNG stream.
-
-    ``materialize`` maps the jit-boundary param pytree (possibly
-    ``SealedTensor`` ciphertext leaves) to the serving param view.
-    """
-    def decode_step(tensors, pools, tables, lengths, wc, tokens, key_data,
-                    counts, temperature, top_k, top_p):
-        params = materialize(tensors)
-        logits, updates, _ = PG.decode_logits(cfg, params, pools, tables,
-                                              lengths, wc, tokens, cache_seal)
-        pools = PG.apply_paged_updates(cfg, cache_seal, pools, updates,
-                                       tables, lengths, wc)
-        keys = SM.fold_token_keys(key_data, counts)
-        tok = SM.sample_logits(logits, keys, temperature, top_k, top_p)
-        return tok, logits, pools
-    return decode_step
-
-
 def make_paged_prefill(cfg: ModelConfig, materialize, cache_seal):
     """Ragged admission prefill: run a right-padded (A, S_bucket) batch,
     seal its KV into the admitted slots' pool blocks, and sample each

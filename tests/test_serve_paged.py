@@ -47,18 +47,17 @@ def _paged_teacher_forced(cfg, params, toks, seal):
     pools = PG.prefill_write(cfg, seal, pools, cache,
                              jnp.asarray(block_tables), jnp.asarray(wc))
     out = [logits]
-    lengths = np.full((b,), PLEN, np.int32)
+    tables = jnp.asarray(tables)
+    wc = jnp.asarray(wc)
+    lengths = jnp.full((b,), PLEN, jnp.int32)
+    ones = jnp.ones((b,), jnp.int32)
     for t in range(STEPS):
         step_tok = toks[:, PLEN + t][:, None]
         logits, updates, _ = PG.decode_logits(
-            cfg, params, pools, jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(wc), step_tok, seal)
-        pools = PG.apply_paged_updates(
-            cfg, seal, pools, updates, jnp.asarray(tables),
-            jnp.asarray(lengths), jnp.asarray(wc))
-        pb = tables[np.arange(b), lengths // BS]
-        wc[pb] += 1                          # mirror the seal-on-write bump
-        lengths += 1
+            cfg, params, pools, tables, lengths, wc, step_tok, seal)
+        pools, wc = PG.append_tokens(cfg, seal, pools, updates, tables,
+                                     lengths, ones, wc)
+        lengths = lengths + 1
         out.append(logits)
     return jnp.stack(out)
 
