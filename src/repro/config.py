@@ -20,12 +20,48 @@ BLOCK_KINDS = ("attn", "local_attn", "rglru", "ssd")
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int                 # routed experts the router scores
     top_k: int
     # load-balancing aux loss weight (used in training)
     aux_loss_weight: float = 0.01
     # expert-capacity factor for GShard-style dispatch (train/prefill)
     capacity_factor: float = 1.25
+    # how tokens pick experts, and so which layer serves them:
+    #   "softmax": softmax top-k (qwen3, dbrx): ``layers.moe_apply`` with
+    #     its capacity, ``moe_apply_dense`` when decoding;
+    #   "sigmoid_bias": DeepSeek-V3's top-k of sigmoid score + a correction
+    #     bias, weighted by the scores: ``layers.moe_held``, dropless, told
+    #     which experts it holds, with shared experts.
+    router: str = "softmax"
+    d_expert: int = 0                # routed expert width (0: d_ff)
+    d_shared: int = 0                # shared experts as one MLP (0: none)
+    route_scale: float = 1.0         # routed_scaling_factor
+    # experts this chip holds (0: all), experts [first, first + held) of
+    # every MoE layer: expert parallelism's share of one chip
+    experts_held: int = 0
+
+    def __post_init__(self):
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"unknown router {self.router!r}")
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA:
+    the cache holds one ``kv_lora_rank + rope_dim`` latent per token, shared
+    by every head."""
+    kv_lora_rank: int
+    nope_dim: int                    # qk_nope_head_dim
+    rope_dim: int                    # qk_rope_head_dim
+    v_dim: int                       # v_head_dim
+
+    @property
+    def latent(self) -> int:
+        return self.kv_lora_rank + self.rope_dim
 
 
 @dataclass(frozen=True)
@@ -42,6 +78,10 @@ class ModelConfig:
     # periodic layer pattern, cycled over num_layers
     pattern: Tuple[str, ...] = ("attn",)
     moe: Optional[MoEConfig] = None
+    # leading layers with a dense MLP of width d_ff ahead of the MoE stack
+    # (DeepSeek's first_k_dense_replace); a stack of their own in params
+    first_dense: int = 0
+    mla: Optional[MLAConfig] = None
     # gemma-style softcaps / local attention
     logit_softcap: float = 0.0
     attn_softcap: float = 0.0
@@ -109,8 +149,14 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += self.vocab_size * d  # lm head
         kinds = self.layer_kinds()
-        for k in kinds:
-            if k in ("attn", "local_attn"):
+        for i, k in enumerate(kinds):
+            moe = self.moe if i >= self.first_dense else None
+            if k in ("attn", "local_attn") and self.mla is not None:
+                a, h = self.mla, self.num_heads
+                total += (d * self.q_dim + d * a.latent + a.kv_lora_rank
+                          + a.kv_lora_rank * h * (a.nope_dim + a.v_dim)
+                          + h * a.v_dim * d)
+            elif k in ("attn", "local_attn"):
                 total += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
             elif k == "rglru":
                 w = self.rglru_block_width or self.d_model
@@ -124,9 +170,14 @@ class ModelConfig:
                 total += self.ssm_conv * (di + nbc) + 2 * self.ssm_heads
             # MLP
             if k != "ssd" and self.d_ff:
-                if self.moe is not None:
-                    e = self.moe.top_k if active_only else self.moe.num_experts
-                    total += e * (3 * d * self.d_ff) + d * self.moe.num_experts
+                if moe is not None and moe.router == "sigmoid_bias":
+                    m = moe
+                    e = m.top_k if active_only else m.held
+                    total += (e * 3 * d * (m.d_expert or self.d_ff)
+                              + 3 * d * m.d_shared + (d + 1) * m.num_experts)
+                elif moe is not None:
+                    e = moe.top_k if active_only else moe.num_experts
+                    total += e * (3 * d * self.d_ff) + d * moe.num_experts
                 else:
                     total += 3 * d * self.d_ff
             total += 2 * d  # norms

@@ -1,7 +1,9 @@
 """Architecture registry: ``get_config(arch_id)`` / ``get_reduced(arch_id)``.
 
 Each module defines ``config()`` (the exact published configuration) and
-``reduced()`` (a small same-family config for CPU smoke tests).
+``reduced()`` (a small same-family config for CPU smoke tests). A share id
+(``SHARE_IDS``) names what one chip holds of a stated deployment of an
+architecture, built by a function of that architecture's module.
 """
 from __future__ import annotations
 
@@ -21,7 +23,11 @@ ARCH_IDS = [
     "recurrentgemma_9b",
     "musicgen_medium",
     "mamba2_130m",
+    "moonlight_16b_a3b",
 ]
+
+# share id -> (architecture, function of its module giving one chip's share)
+SHARE_IDS = {"moonlight_16b_a3b_ep8": ("moonlight_16b_a3b", "ep8_share")}
 
 CNN_IDS = ["vgg16", "resnet18", "resnet34"]
 
@@ -36,11 +42,14 @@ def _module(arch_id: str):
 
 
 def get_config(arch_id: str):
+    if arch_id in SHARE_IDS:
+        arch, fn = SHARE_IDS[arch_id]
+        return getattr(_module(arch), fn)()
     return _module(arch_id).config()
 
 
 def get_reduced(arch_id: str):
-    return _module(arch_id).reduced()
+    return _module(SHARE_IDS.get(arch_id, (arch_id,))[0]).reduced()
 
 
 def all_configs() -> dict:

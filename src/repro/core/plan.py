@@ -6,7 +6,7 @@ Classifies every leaf (by its path) into:
   * ``full`` — tiny tensors (norm scales, biases, conv filters of the
     modality stubs, SSM scalars) that are always fully encrypted;
 plus boundary protection: the embedding, the LM head, and the first/last
-super-block are always fully encrypted (the LM analogue of the paper's
+super-block of each layer stack are always fully encrypted (the LM analogue of the paper's
 "first two CONV layers, last CONV, last FC" rule, §3.4.1).
 """
 from __future__ import annotations
@@ -46,9 +46,15 @@ def _classify(path: Tuple[str, ...], ndim: int):
         return (0,), (1,)
     if parent == "attn" and name == "wo":
         return (0,), (1, 2)          # rows = (head, head_dim) inputs
-    if parent == "mlp" and name in ("wi", "wg", "wo"):
+    if parent == "attn" and name == "wk_b":
+        return (0,), (1, 2)          # MLA: rows = (head, nope) inputs
+    if parent == "attn" and name in ("wkv_a", "wk_rope", "wv_b"):
+        return (0,), (1,)
+    if parent in ("mlp", "experts") and name in ("wi", "wg", "wo"):
         if ndim == 4:                # MoE: (n, e, d_in, d_out)
             return (0, 1), (2,)
+        return (0,), (1,)
+    if parent == "shared" and name in ("wi", "wg", "wo"):
         return (0,), (1,)
     if name == "router":
         return (0,), (1,)
@@ -92,7 +98,8 @@ def make_plan(params, seal: SealConfig) -> Dict[str, LeafPlan]:
         batch_axes, row_axes = cls
         imp = row_importance(leaf, row_axes, batch_axes)
         mask = encryption_mask(imp, ratio)
-        if seal.protect_boundary_layers and path[0] == "blocks" and mask.ndim >= 1 \
+        if seal.protect_boundary_layers and \
+                path[0] in ("blocks", "dense_blocks") and mask.ndim >= 1 \
                 and batch_axes[:1] == (0,):
             # first & last super-block fully encrypted
             mask = mask.at[0].set(True)

@@ -5,15 +5,20 @@ image an adversary could probe — DESIGN.md §2) and decrypt on use.
 engine (direct / counter / coloe) per leaf, producing one ``SealedTensor``
 per leaf:
 
-* matmul-shaped leaves (attention wq/wk/wv/wo, dense-MLP wi/wg/wo, the LM
-  head) get the **tile-sealed layout** when ``seal.fuse_decrypt`` is on and
-  the engine is counter-mode: they flow *still sealed* through the jitted
-  serving graph into ``kernels.sealed_matmul`` and are decrypted in-register
-  under their SE row masks — the plaintext weight never exists in HBM;
+* matmul-shaped leaves (attention wq/wk/wv/wo, MLA's wkv_a/wk_rope/wk_b/wv_b,
+  dense- and shared-MLP wi/wg/wo, the LM head) get the **tile-sealed
+  layout** when ``seal.fuse_decrypt`` is on and the engine is counter-mode:
+  they flow *still sealed* through the jitted serving graph into
+  ``kernels.sealed_matmul`` and are decrypted in-register under their SE
+  row masks — the plaintext weight never exists in HBM;
+* the held experts of a dropless MoE layer (``experts/{wi,wg,wo}``, a
+  layer x expert stack) are tile-sealed per (layer, expert) and flow still
+  sealed into ``kernels.sealed_gmm`` the same way;
 * the token embedding is tile-sealed too (an unpadded layout on a TPU)
   but decrypted eagerly in-graph, since its consumer is a gather;
-* everything else (norms, MoE experts, recurrent/SSM weights) gets the
-  **line-packed at-rest layout** and is decrypted eagerly in-graph.
+* everything else (norms, routers, capacity-routed MoE experts,
+  recurrent/SSM weights) gets the **line-packed at-rest layout** and is
+  decrypted eagerly in-graph.
 
 ``unseal_params`` decrypts every leaf (both layouts, jittable);
 ``fused_params`` passes the matmul leaves through as ``SealedTensor`` and
@@ -99,6 +104,13 @@ class CacheSeal:
     # per stream (``mac_k``/``mac_v``), written on every sealed write and
     # checked on every gather/read (``models/paged.py``)
     mac: Optional[M.MacContext] = None
+    # the nonce of an MLA pool's one latent stream
+    nonce_c: Tuple[int, int, int] = (0, 0, 0)
+
+    def nonce(self, stream: str) -> Tuple[int, int, int]:
+        """The nonce of pool stream ``stream`` ("k", "v" or "c")."""
+        return {"k": self.nonce_k, "v": self.nonce_v,
+                "c": self.nonce_c}[stream]
 
 
 def cache_seal_config(key_bytes: bytes, verify: bool = False) -> CacheSeal:
@@ -108,7 +120,8 @@ def cache_seal_config(key_bytes: bytes, verify: bool = False) -> CacheSeal:
     from repro.core import cipher as C
     return CacheSeal(jnp.asarray(C.key_to_words(key_bytes[:32])),
                      _nonce3("kvcache/k"), _nonce3("kvcache/v"),
-                     M.mac_context(key_bytes, "kvcache") if verify else None)
+                     M.mac_context(key_bytes, "kvcache") if verify else None,
+                     _nonce3("kvcache/c"))
 
 
 def line_flags_from_mask(mask_elems, dtype, n_lines: int) -> jnp.ndarray:
@@ -128,12 +141,18 @@ def line_flags_from_mask(mask_elems, dtype, n_lines: int) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 
 # (parent, name) pairs whose consumption sites are threaded through
-# SealedTensor.matmul in models/. MoE experts (4-D, expert-batched), the
-# router, recurrent/SSM projections and the embedding stay on the eager path
-# for now (ROADMAP open item).
+# SealedTensor.matmul in models/. Capacity-routed MoE experts (4-D under
+# "mlp"), the router, recurrent/SSM projections and the embedding stay on
+# the eager path for now (ROADMAP open item).
 _FUSED_LEAVES = {("attn", "wq"), ("attn", "wk"), ("attn", "wv"),
-                 ("attn", "wo"), ("mlp", "wi"), ("mlp", "wg"),
-                 ("mlp", "wo"), ("head", "w")}
+                 ("attn", "wo"), ("attn", "wkv_a"), ("attn", "wk_rope"),
+                 ("attn", "wk_b"), ("attn", "wv_b"), ("mlp", "wi"),
+                 ("mlp", "wg"), ("mlp", "wo"), ("shared", "wi"),
+                 ("shared", "wg"), ("shared", "wo"), ("head", "w")}
+# Expert stacks (layer, expert, K, N) consumed through SealedTensor.gmm:
+# the only leaves sealed with two stack axes, one write counter per
+# (layer, expert) slice.
+_GMM_LEAVES = {("experts", "wi"), ("experts", "wg"), ("experts", "wo")}
 # Leaves stored tile-sealed but decrypted eagerly in-graph (their consumer
 # is a gather, not a matmul). The tile layout keeps a (V, D) table as V x D
 # words, which a TPU holds unpadded and (un)seals one row of tiles at a
@@ -143,7 +162,7 @@ _EAGER_TILE_LEAVES = {("embed", "w")}
 
 
 class TileGeometry(NamedTuple):
-    n_batch: int          # leading stack axes
+    n_batch: int          # leading stack axes (2 for an expert stack)
     k_ndim: int           # contraction (row) axes
     n_out: int            # trailing output axes
     k: int
@@ -155,11 +174,12 @@ class TileGeometry(NamedTuple):
 
 def _pick_block(dim: int) -> Optional[int]:
     # a tile's pad is 16 keystream-word planes stacked along its rows, so
-    # a tile side is a multiple of 16 (``kernels.ref.tile_counters``)
-    for b in (128, 64, 32, 16):
-        if dim % b == 0:
-            return b
-    return None
+    # a tile side is a multiple of 16 (``kernels.ref.tile_counters``); a
+    # TPU block side is a multiple of 128 or the whole dimension (MLA's
+    # 64-wide rotary key projection takes one tile across)
+    if dim % 128 == 0:
+        return 128
+    return dim if dim % 16 == 0 else None
 
 
 def tile_geometry(path: Tuple[str, ...], shape, dtype,
@@ -174,7 +194,8 @@ def tile_geometry(path: Tuple[str, ...], shape, dtype,
         return None
     keys = {(path[-2] if len(path) >= 2 else "", path[-1]),
             (path[0], path[-1])}
-    fused = bool(keys & _FUSED_LEAVES)
+    gmm = bool(keys & _GMM_LEAVES)
+    fused = gmm or bool(keys & _FUSED_LEAVES)
     if not fused and not keys & _EAGER_TILE_LEAVES:
         return None
     if jnp.dtype(dtype).itemsize != 4:
@@ -184,7 +205,7 @@ def tile_geometry(path: Tuple[str, ...], shape, dtype,
         return None
     batch_axes, row_axes = cls
     nb, nk = len(batch_axes), len(row_axes)
-    if nb > 1 or batch_axes != tuple(range(nb)) or \
+    if nb > (2 if gmm else 1) or batch_axes != tuple(range(nb)) or \
             row_axes != tuple(range(nb, nb + nk)):
         return None
     n_out = len(shape) - nb - nk
@@ -193,8 +214,8 @@ def tile_geometry(path: Tuple[str, ...], shape, dtype,
     k = int(np.prod(shape[nb:nb + nk]))
     n = int(np.prod(shape[nb + nk:]))
     bk, bn = _pick_block(k), _pick_block(n)
-    if bk is None or bn is None:
-        return None
+    if bk is None or bn is None or bk * bn > M.MAX_WORDS:
+        return None                       # a tile's tag covers one tile
     return TileGeometry(nb, nk, n_out, k, n, bk, bn, fused)
 
 
@@ -224,29 +245,38 @@ def _seal_lines(eng, seal, leaf, plan, path) -> SealedTensor:
                         meta, macs=macs)
 
 
+def _flat_lead(shape, nb: int):
+    """The stack axes of a tile-sealed leaf as one: () or (slices,)."""
+    return (int(np.prod(shape[:nb])),) if nb else ()
+
+
 def _seal_tiles(eng, seal, leaf, plan, path, geom) -> SealedTensor:
     nb, nk, n_out, k, n, bk, bn, fused = geom
     nonce3 = _nonce3(path)
     shape = leaf.shape
+    lead, flat = shape[:nb], _flat_lead(shape, nb)
     if plan.mask is not None:
-        mask = plan.mask.reshape(plan.mask.shape[:nb] + (k,))
+        mask = plan.mask.reshape(lead + (k,))
     else:
-        mask = jnp.ones(shape[:nb] + (k,), bool)
+        mask = jnp.ones(lead + (k,), bool)
     key_arr = jnp.asarray(eng.key_words, jnp.uint32)
-    lead = shape[:nb]
-    # one write-counter per stack slice: the (key, nonce, counter) triple —
-    # hence the OTP — is never reused across layers
-    wc = (jnp.arange(shape[0], dtype=jnp.uint32) if nb
+    # one write-counter per stack slice (a layer, or a layer's expert):
+    # the (key, nonce, counter) triple — hence the OTP — is never reused
+    # across layers or experts
+    wc = (jnp.arange(flat[0], dtype=jnp.uint32).reshape(lead) if nb
           else jnp.zeros((), jnp.uint32))
     key_c = jnp.broadcast_to(key_arr, lead + (8,))
-    ct2d = eng.encrypt_tiles(leaf.reshape(lead + (k, n)), nonce3, mask, wc,
+    ct2d = eng.encrypt_tiles(leaf.reshape(flat + (k, n)), nonce3,
+                             mask.reshape(flat + (k,)), wc.reshape(flat),
                              bk, bn)
     payload = ct2d.reshape(shape)
     meta = SealMeta(scheme=eng.name, layout="tiles",
                     dtype=str(jnp.dtype(leaf.dtype)), nonce=nonce3,
                     shape=tuple(shape), n_batch=nb, k_ndim=nk, n_out=n_out,
                     bk=bk, bn=bn, fused=fused)
-    macs = (M.tile_tags(eng.mac_ctx, ct2d, mask, wc, bk, bn, tweak=nonce3)
+    macs = (M.tile_tags(eng.mac_ctx, ct2d, mask.reshape(flat + (k,)),
+                        wc.reshape(flat), bk, bn, tweak=nonce3
+                        ).reshape(lead + (k // bk, n // bn))
             if seal.verify else None)
     return SealedTensor(payload, None, mask, key_c, wc, meta, macs=macs)
 
@@ -287,9 +317,10 @@ def _unseal_tensor(eng, st: SealedTensor):
         nb = m.n_batch
         k = int(np.prod(m.shape[nb:nb + m.k_ndim]))
         n = int(np.prod(m.shape[nb + m.k_ndim:]))
-        lead = m.shape[:nb]
-        w = eng.decrypt_tiles(st.payload.reshape(lead + (k, n)), m.nonce,
-                              st.row_mask, st.wc, m.bk, m.bn)
+        flat = _flat_lead(m.shape, nb)
+        w = eng.decrypt_tiles(st.payload.reshape(flat + (k, n)), m.nonce,
+                              st.row_mask.reshape(flat + (k,)),
+                              st.wc.reshape(flat), m.bk, m.bn)
         return w.reshape(m.shape).astype(jnp.dtype(m.dtype))
     buf = E.SealedBuffer(m.scheme, st.payload, st.counters, m.orig_len,
                          m.shape, jnp.dtype(m.dtype), m.nonce)
@@ -340,9 +371,11 @@ def verify_params(sp: SealedParams, key_bytes: bytes):
             nb = m.n_batch
             k = int(np.prod(m.shape[nb:nb + m.k_ndim]))
             n = int(np.prod(m.shape[nb + m.k_ndim:]))
-            ct2d = st.payload.reshape(((m.shape[0],) if nb else ()) + (k, n))
-            tags = M.tile_tags(eng.mac_ctx, ct2d, st.row_mask, st.wc,
-                               m.bk, m.bn, tweak=m.nonce)
+            flat = _flat_lead(m.shape, nb)
+            tags = M.tile_tags(eng.mac_ctx, st.payload.reshape(flat + (k, n)),
+                               st.row_mask.reshape(flat + (k,)),
+                               st.wc.reshape(flat), m.bk, m.bn,
+                               tweak=m.nonce).reshape(st.macs.shape)
         else:
             buf = E.SealedBuffer(m.scheme, st.payload, st.counters,
                                  m.orig_len, m.shape, jnp.dtype(m.dtype),

@@ -178,6 +178,28 @@ class SealedTensor:
             interpret=interpret)
 
 
+    def gmm(self, xs, *, compute_dtype: str = "float32", interpret=None):
+        """Fused decrypt-in-grouped-matmul over one layer's expert stack:
+        ``xs`` (E, T, K), expert e's slab, against expert e's (K, N)
+        weight, each decrypted in-register by ``kernels.sealed_gmm`` under
+        its own write counter. Tiles layout with an expert axis under the
+        layer axis, once the layer axis was sliced away."""
+        m = self.meta
+        if m.layout != "tiles" or m.n_batch != 2 or \
+                self.payload.ndim != m.k_ndim + m.n_out + 1:
+            raise ValueError(f"gmm needs one layer of a tile-sealed expert "
+                             f"stack, got {self!r}")
+        from repro.kernels import ops   # deferred: core must import cheaply
+        e = self.payload.shape[0]
+        return ops.sealed_gmm(
+            xs, self.payload.reshape(e, self.k_size, self.n_size),
+            self.row_mask.reshape(e, self.k_size),
+            self.key_words.reshape(e, 8)[0],
+            jnp.asarray(m.nonce, jnp.uint32),
+            write_counters=self.wc.reshape(e), bk=m.bk, bn=m.bn,
+            compute_dtype=compute_dtype, interpret=interpret)
+
+
 jax.tree_util.register_pytree_node(
     SealedTensor,
     lambda st: st.tree_flatten(),

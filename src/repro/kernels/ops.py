@@ -16,6 +16,7 @@ import numpy as np
 from repro.kernels import chacha20 as _cc
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as _ref
+from repro.kernels import sealed_gmm as _sg
 from repro.kernels import sealed_matmul as _sm
 
 
@@ -62,6 +63,23 @@ def sealed_matmul(x, w_ct, row_mask, key_words, nonce_words,
                             bm=bm, bk=bk, bn=bn, interpret=interpret,
                             compute_dtype=compute_dtype)
     return out[:m]
+
+
+def sealed_gmm(x, w_ct, row_mask, key_words, nonce_words, write_counters, *,
+               bk: int = 128, bn: int = 128, interpret=None,
+               compute_dtype: str = "float32"):
+    """Grouped fused decrypt+matmul over a stack of sealed experts: x
+    (E, T, K), w_ct (E, K, N) u32, one write counter per expert. The slab
+    rows T are padded here to a multiple of 8."""
+    interpret = _default_interpret() if interpret is None else interpret
+    t = x.shape[1]
+    pad = (-t) % 8
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    out = _sg.sealed_gmm(x, w_ct, row_mask, key_words, nonce_words,
+                         write_counters, bk=bk, bn=bn, interpret=interpret,
+                         compute_dtype=compute_dtype)
+    return out[:, :t]
 
 
 def flash_attention(q, k, v, *, scale: float, softcap: float = 0.0,

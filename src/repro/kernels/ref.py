@@ -135,3 +135,15 @@ def sealed_matmul_ref(x, wct, key_words, nonce_words, bk: int, bn: int,
                            write_counter)
     return jnp.dot(x.astype(jnp.float32), w,
                    preferred_element_type=jnp.float32)
+
+
+def sealed_gmm_ref(x, wct, key_words, nonce_words, bk: int, bn: int,
+                   row_mask, write_counters):
+    """Oracle of ``sealed_gmm``: decrypt each expert's weight under its own
+    write counter, then a plain batched matmul. x (E, T, K), wct (E, K, N),
+    row_mask (E, K), write_counters (E,)."""
+    w = jnp.stack([unseal_weights_ref(wct[e], key_words, nonce_words, bk, bn,
+                                      row_mask[e], write_counters[e])
+                   for e in range(wct.shape[0])])
+    return jnp.einsum("etk,ekn->etn", x.astype(jnp.float32), w,
+                      preferred_element_type=jnp.float32)

@@ -37,36 +37,41 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.chacha20 import _chacha_rounds, _CONST
 
 
-def _make_kernel(bk, bn, nn_tiles, uniq, compute_dtype):
+def tile_plaintext(key_ref, nonce_ref, wc, tile_id, w_ref, mask_ref, *, bk,
+                   bn, uniq):
+    """The plaintext of the (bk, bn) weight tile in ``w_ref`` (u32) under
+    write counter ``wc``: its ChaCha pad (``ref.tile_counters``), XORed in
+    registers into the rows ``mask_ref`` flags; as f32."""
     rpp = bk // 16                     # rows per keystream-word plane
     nblk = rpp * bn                    # ChaCha blocks per weight tile
+    base = wc * jnp.uint32(uniq) + (tile_id * nblk).astype(jnp.uint32)
+    ctr = base + (jax.lax.broadcasted_iota(jnp.uint32, (rpp, bn), 0)
+                  * jnp.uint32(bn)
+                  + jax.lax.broadcasted_iota(jnp.uint32, (rpp, bn), 1))
+
+    init = [jnp.full((rpp, bn), _CONST[i], jnp.uint32) for i in range(4)]
+    init += [jnp.full((rpp, bn), key_ref[i], jnp.uint32) for i in range(8)]
+    init.append(ctr)
+    init += [jnp.full((rpp, bn), nonce_ref[i], jnp.uint32) for i in range(3)]
+    x16 = _chacha_rounds(list(init))
+    pad = jnp.concatenate([x16[i] + init[i] for i in range(16)], axis=0)
+    wu = w_ref[...]
+    wpt = jnp.where(mask_ref[...] != 0, wu ^ pad, wu)
+    return jax.lax.bitcast_convert_type(wpt, jnp.float32)
+
+
+def _make_kernel(bk, bn, nn_tiles, uniq, compute_dtype):
     cdt = jnp.dtype(compute_dtype)
 
     def kernel(key_ref, nonce_ref, wc_ref, x_ref, w_ref, mask_ref, out_ref):
         j_idx = pl.program_id(1)
         k_idx = pl.program_id(2)
         tile_id = k_idx * nn_tiles + j_idx
-        base = (wc_ref[0] * jnp.uint32(uniq)
-                + (tile_id * nblk).astype(jnp.uint32))
-        ctr = base + (jax.lax.broadcasted_iota(jnp.uint32, (rpp, bn), 0)
-                      * jnp.uint32(bn)
-                      + jax.lax.broadcasted_iota(jnp.uint32, (rpp, bn), 1))
-
-        init = [jnp.full((rpp, bn), _CONST[i], jnp.uint32) for i in range(4)]
-        init += [jnp.full((rpp, bn), key_ref[i], jnp.uint32)
-                 for i in range(8)]
-        init.append(ctr)
-        init += [jnp.full((rpp, bn), nonce_ref[i], jnp.uint32)
-                 for i in range(3)]
-        x16 = _chacha_rounds(list(init))
-        pad = jnp.concatenate([x16[i] + init[i] for i in range(16)], axis=0)
-
-        wu = w_ref[...]
-        wpt = jnp.where(mask_ref[...] != 0, wu ^ pad, wu)
         # match the unfused model path's precision: weights/activations are
         # rounded to the model compute dtype before the MXU contraction,
         # which always accumulates in f32
-        wf = jax.lax.bitcast_convert_type(wpt, jnp.float32).astype(cdt)
+        wf = tile_plaintext(key_ref, nonce_ref, wc_ref[0], tile_id, w_ref,
+                            mask_ref, bk=bk, bn=bn, uniq=uniq).astype(cdt)
         acc = jnp.dot(x_ref[...].astype(cdt), wf,
                       preferred_element_type=jnp.float32)
 

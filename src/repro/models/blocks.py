@@ -1,7 +1,7 @@
 """Residual blocks: attention (global/local), RG-LRU (Griffin), Mamba2-SSD.
 
 Each block exposes:
-  init_block(cfg, kind, key)                          -> params
+  init_block(cfg, kind, key[, dense])                 -> params
   block_apply(cfg, kind, params, x, positions, mode, cache) -> (y, cache', aux)
 
 mode: "train" | "prefill" | "decode" | "chunk". In decode mode x is
@@ -286,10 +286,14 @@ def ssd_block_apply(cfg: ModelConfig, p, x, mode, cache):
 # unified block init/apply
 # --------------------------------------------------------------------------
 
-def init_block(cfg: ModelConfig, kind: str, key):
+def init_block(cfg: ModelConfig, kind: str, key, dense: bool = False):
+    """``dense``: a leading dense layer of a MoE model (its MLP is of width
+    ``d_ff``)."""
     k1, k2, k3 = jax.random.split(key, 3)
     p = {"norm1": L.init_norm(cfg, k1)}
-    if kind in ("attn", "local_attn"):
+    if kind in ("attn", "local_attn") and cfg.mla is not None:
+        p["attn"] = L.init_mla(cfg, k2)
+    elif kind in ("attn", "local_attn"):
         p["attn"] = L.init_attention(cfg, k2)
     elif kind == "rglru":
         p["rec"] = init_rglru(cfg, k2)
@@ -299,8 +303,57 @@ def init_block(cfg: ModelConfig, kind: str, key):
         raise ValueError(kind)
     if kind != "ssd" and cfg.d_ff:
         p["norm2"] = L.init_norm(cfg, k3)
-        p["mlp"] = L.init_mlp(cfg, k3)
+        if dense:
+            p["mlp"] = L.init_mlp(cfg.with_(moe=None), k3)
+        elif cfg.moe is not None and cfg.moe.router == "sigmoid_bias":
+            p["mlp"] = L.init_moe_held(cfg, k3)
+        else:
+            p["mlp"] = L.init_mlp(cfg, k3)
     return p
+
+
+def mla_block_sub_apply(cfg: ModelConfig, p, h, positions, mode, cache):
+    """MLA's counterpart of ``attn_block_sub_apply``: the cache holds one
+    latent entry per token ("c"), and every mode runs the absorbed
+    attention over the latents. Decode and chunk modes emit the tokens'
+    new entries as {"c_new"}; prefill returns the padded contiguous
+    cache."""
+    lat = L.mla_latent(cfg, p, h, positions)               # (b, s, width)
+    if mode == "decode":
+        dt = cache["c"].dtype
+        lat_att = jnp.concatenate([cache["c"], lat.astype(dt)], axis=1)
+        if positions.ndim == 2:
+            pos_att = jnp.concatenate([cache["pos"], positions], axis=1)
+        else:
+            pos_att = jnp.concatenate([cache["pos"], positions[0][None]],
+                                      axis=0)
+        out = L.mla_apply(cfg, p, h, positions, lat_att, pos_att)
+        return out, {"c_new": lat.astype(dt)}
+    if mode == "chunk":
+        # as attn_block_sub_apply: the chunk's entries land at their
+        # absolute positions in the identity-indexed view
+        dt = cache["c"].dtype
+        w = cache["c"].shape[1]
+        c = positions.shape[1]
+        tgt = jnp.where(jnp.arange(c)[None, :] < cache["cl"][:, None],
+                        positions, w)
+        lat_att = jax.vmap(lambda cc, ti, ln: cc.at[ti].set(ln, mode="drop"))(
+            cache["c"], tgt, lat.astype(dt))
+        out = L.mla_apply(cfg, p, h, positions, lat_att, cache["pos"])
+        return out, {"c_new": lat.astype(dt)}
+    out = L.mla_apply(cfg, p, h, positions, lat, positions)
+    new_cache = None
+    if mode == "prefill":
+        cache_len = cache["c"].shape[1]
+        s = lat.shape[1]
+        assert s <= cache_len, (s, cache_len)
+        pad = cache_len - s
+        new_cache = {
+            "c": jnp.pad(lat, ((0, 0), (0, pad), (0, 0))).astype(
+                cache["c"].dtype),
+            "pos": jnp.pad(positions, (0, pad), constant_values=INVALID_POS
+                           ).astype(jnp.int32)}
+    return out, new_cache
 
 
 def attn_block_sub_apply(cfg: ModelConfig, kind: str, p, h, positions, mode, cache):
@@ -380,7 +433,10 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode, cache):
     """Returns (x_out, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     h = L.apply_norm(cfg, p["norm1"], x)
-    if kind in ("attn", "local_attn"):
+    if kind in ("attn", "local_attn") and cfg.mla is not None:
+        sub, new_cache = mla_block_sub_apply(cfg, p["attn"], h, positions,
+                                             mode, cache)
+    elif kind in ("attn", "local_attn"):
         sub, new_cache = attn_block_sub_apply(cfg, kind, p["attn"], h, positions,
                                               mode, cache)
     elif kind == "rglru":
@@ -390,17 +446,34 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode, cache):
     else:
         raise ValueError(kind)
     x = x + sub.astype(x.dtype)
+    routes = None
     if kind != "ssd" and cfg.d_ff:
         h2 = L.apply_norm(cfg, p["norm2"], x)
-        if cfg.moe is not None:
+        if "router" not in p["mlp"]:
+            m = L.mlp_apply(cfg, p["mlp"], h2)       # incl. leading dense
+        elif cfg.moe.router == "sigmoid_bias":
+            # the tokens the route counters count: a chunk's real prompt
+            # tokens, the slots that decode
+            live = None
+            if mode == "chunk":
+                live = (jnp.arange(h2.shape[1])[None, :]
+                        < cache["cl"][:, None])
+            elif mode == "decode":
+                live = cache.get("live")
+            m, routes = L.moe_held(cfg, p["mlp"], h2, live)
+        else:
             if mode == "decode":
                 # dropless dense path: exact for tiny decode token counts
                 m, aux = L.moe_apply_dense(cfg, p["mlp"], h2)
             else:
                 m, aux = L.moe_apply(cfg, p["mlp"], h2)
-        else:
-            m = L.mlp_apply(cfg, p["mlp"], h2)
         x = x + m.astype(x.dtype)
+    if (mode in ("decode", "chunk") and cfg.moe is not None
+            and cfg.moe.router == "sigmoid_bias"):
+        # the update record carries the layer's route counters (zero for a
+        # leading dense layer), summed into the serving counters
+        new_cache = dict(new_cache, routes=(
+            routes if routes is not None else jnp.zeros((2,), jnp.uint32)))
     # sequence-parallel residual stream (Megatron-SP): the scan carry —
     # which the bwd pass stacks per layer — shards its seq dim over
     # `model` when the run enables the "seq_res" rule. 16x smaller

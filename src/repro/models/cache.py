@@ -35,6 +35,11 @@ def attn_cache_spec(cfg: ModelConfig, batch: int, cache_len: int, kind: str):
     if kind == "local_attn" and cfg.window:
         cache_len = min(cache_len, cfg.window)
     dt = jnp.dtype(cfg.dtype)
+    if cfg.mla is not None:
+        return {
+            "c": jax.ShapeDtypeStruct((batch, cache_len, cfg.mla.latent), dt),
+            "pos": jax.ShapeDtypeStruct((cache_len,), jnp.int32),
+        }
     return {
         "k": jax.ShapeDtypeStruct((batch, cache_len, cfg.num_kv_heads, cfg.head_dim), dt),
         "v": jax.ShapeDtypeStruct((batch, cache_len, cfg.num_kv_heads, cfg.head_dim), dt),
@@ -44,11 +49,8 @@ def attn_cache_spec(cfg: ModelConfig, batch: int, cache_len: int, kind: str):
 
 def attn_cache_init(cfg: ModelConfig, batch: int, cache_len: int, kind: str):
     spec = attn_cache_spec(cfg, batch, cache_len, kind)
-    return {
-        "k": jnp.zeros(spec["k"].shape, spec["k"].dtype),
-        "v": jnp.zeros(spec["v"].shape, spec["v"].dtype),
-        "pos": jnp.full(spec["pos"].shape, INVALID_POS, jnp.int32),
-    }
+    return {k: (jnp.full(v.shape, INVALID_POS, jnp.int32) if k == "pos"
+                else jnp.zeros(v.shape, v.dtype)) for k, v in spec.items()}
 
 
 def rglru_cache_spec(cfg: ModelConfig, batch: int):
@@ -129,10 +131,27 @@ def model_cache_init(cfg: ModelConfig, batch: int, cache_len: int):
 # paged block pools (continuous serving)
 # --------------------------------------------------------------------------
 
+def pool_streams(cfg: ModelConfig):
+    """The word streams of one layer's paged pool: one latent entry per
+    token ("c") for MLA, else K and V."""
+    return ("c",) if cfg.mla is not None else ("k", "v")
+
+
+def token_shape(cfg: ModelConfig):
+    """Shape of one token's entry in each stream."""
+    if cfg.mla is not None:
+        return (cfg.mla.latent,)
+    return (cfg.num_kv_heads, cfg.head_dim)
+
+
 def kv_words_per_token(cfg: ModelConfig) -> int:
-    """u32 words one token's K (or V) occupies in a pool block."""
-    nbytes = cfg.num_kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    assert nbytes % 4 == 0, (cfg.num_kv_heads, cfg.head_dim, cfg.dtype)
+    """u32 words one token's entry of one stream (K, V or the latent)
+    occupies in a pool block."""
+    elems = 1
+    for n in token_shape(cfg):
+        elems *= n
+    nbytes = elems * jnp.dtype(cfg.dtype).itemsize
+    assert nbytes % 4 == 0, (token_shape(cfg), cfg.dtype)
     return nbytes // 4
 
 
@@ -166,25 +185,27 @@ def words_to_kv(words, dtype):
 def paged_pool_spec(cfg: ModelConfig, num_blocks: int, block_size: int):
     """ShapeDtypeStructs of the paged pools: a tuple over pattern positions
     of {"k", "v": (n_super, num_blocks, words_per_block) u32, "mac_k",
-    "mac_v": (n_super, num_blocks) u32, "lid": (n_super,) u32}. ``lid`` is
+    "mac_v": (n_super, num_blocks) u32, "lid": (n_super,) u32} — for MLA
+    one latent stream {"c", "mac_c"} in place of K and V. ``lid`` is
     the globally unique layer id folded into the block keystream (nonce
-    word 0). ``mac_k``/``mac_v`` are the co-located per-block Carter–Wegman
+    word 0). ``mac_*`` are the co-located per-block Carter–Wegman
     tags (one word per stream — 0.1% of a block); they are always allocated
     so the pool pytree structure is seal-agnostic, and stay zero unless the
     cache seal carries a MAC context."""
     n = cfg.n_superblocks()
     wpb = block_size * kv_words_per_token(cfg)
+    streams = pool_streams(cfg)
     out = []
     for kind in cfg.pattern:
         assert kind in ("attn", "local_attn"), \
             f"paged pools cover attention layers only (got {kind!r})"
-        out.append({
-            "k": jax.ShapeDtypeStruct((n, num_blocks, wpb), jnp.uint32),
-            "v": jax.ShapeDtypeStruct((n, num_blocks, wpb), jnp.uint32),
-            "mac_k": jax.ShapeDtypeStruct((n, num_blocks), jnp.uint32),
-            "mac_v": jax.ShapeDtypeStruct((n, num_blocks), jnp.uint32),
-            "lid": jax.ShapeDtypeStruct((n,), jnp.uint32),
-        })
+        one = {s: jax.ShapeDtypeStruct((n, num_blocks, wpb), jnp.uint32)
+               for s in streams}
+        one.update({f"mac_{s}": jax.ShapeDtypeStruct((n, num_blocks),
+                                                     jnp.uint32)
+                    for s in streams})
+        one["lid"] = jax.ShapeDtypeStruct((n,), jnp.uint32)
+        out.append(one)
     return tuple(out)
 
 
@@ -387,12 +408,9 @@ def paged_pool_init(cfg: ModelConfig, num_blocks: int, block_size: int):
     n, npat = cfg.n_superblocks(), len(cfg.pattern)
     out = []
     for j, sj in enumerate(spec):
-        out.append({
-            "k": jnp.zeros(sj["k"].shape, jnp.uint32),
-            "v": jnp.zeros(sj["v"].shape, jnp.uint32),
-            "mac_k": jnp.zeros(sj["mac_k"].shape, jnp.uint32),
-            "mac_v": jnp.zeros(sj["mac_v"].shape, jnp.uint32),
-            "lid": jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(npat)
-                   + jnp.uint32(j),
-        })
+        one = {k: jnp.zeros(v.shape, jnp.uint32) for k, v in sj.items()
+               if k != "lid"}
+        one["lid"] = (jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(npat)
+                      + jnp.uint32(j))
+        out.append(one)
     return tuple(out)

@@ -357,6 +357,97 @@ def attention_apply(cfg: ModelConfig, p, x, positions, *, window: int,
     return y, kv
 
 
+# --------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3, no query LoRA)
+# --------------------------------------------------------------------------
+
+def init_mla(cfg: ModelConfig, key):
+    """MLA projections. ``wkv_a`` (d, rank + rope) and ``wkv_b`` (rank,
+    heads, nope + v) are drawn whole, as published, and kept in parts: the
+    latent's ``wkv_a`` (d, rank) and the rotary key's ``wk_rope`` (d, rope),
+    whose tiles each fit one integrity tag, and ``wk_b`` (heads, nope, rank)
+    and ``wv_b`` (rank, heads, v), the layouts the absorbed attention
+    consumes."""
+    a, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    wkv_a = jax.random.normal(ka, (d, a.latent)) * d ** -0.5
+    wkv_b = (jax.random.normal(kb, (a.kv_lora_rank, h, a.nope_dim + a.v_dim))
+             * a.kv_lora_rank ** -0.5)
+    return {
+        "wq": (jax.random.normal(kq, (d, h, cfg.head_dim)) * d ** -0.5
+               ).astype(jnp.float32),
+        "wkv_a": wkv_a[:, :a.kv_lora_rank].astype(jnp.float32),
+        "wk_rope": wkv_a[:, a.kv_lora_rank:].astype(jnp.float32),
+        "kv_norm": {"scale": jnp.zeros((a.kv_lora_rank,), jnp.float32)},
+        "wk_b": jnp.transpose(wkv_b[..., :a.nope_dim], (1, 2, 0)
+                              ).astype(jnp.float32),
+        "wv_b": wkv_b[..., a.nope_dim:].astype(jnp.float32),
+        "wo": (jax.random.normal(ko, (h, a.v_dim, d)) * (h * a.v_dim) ** -0.5
+               ).astype(jnp.float32),
+    }
+
+
+def mla_latent(cfg: ModelConfig, p, x, positions):
+    """The cache entry of each token: [RMSNorm(c_kv) ; rope(k_pe)],
+    (b, s, kv_lora_rank + rope_dim) in the compute dtype."""
+    dt = cdtype(cfg)
+    xb = x.astype(dt)
+    c = rmsnorm(dense(xb, p["wkv_a"], "bsd,dc->bsc", dt),
+                p["kv_norm"]["scale"])
+    k_pe = apply_rope(dense(xb, p["wk_rope"], "bsd,dr->bsr", dt)[..., None, :],
+                      positions, cfg.rope_theta)[..., 0, :]
+    return jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+
+
+def _per_head_in(x, w, dt):
+    """out[..., h, c] = sum_n x[..., h, n] w[h, n, c]. A sealed ``w`` is one
+    (heads * n, c) matmul: each head's row reads a block-diagonal copy of
+    its input, zero outside its own head's rows."""
+    if not isinstance(w, SealedTensor):
+        return jnp.einsum("bshn,hnc->bshc", x, w.astype(dt))
+    h = x.shape[-2]
+    eye = jnp.eye(h, dtype=x.dtype)
+    xe = x[..., None, :, :] * eye[:, :, None]            # (b, s, g, h, n)
+    return dense(xe, w, "bsghn,hnc->bsgc", dt)
+
+
+def _per_head_out(x, w, dt):
+    """out[..., h, v] = sum_c x[..., h, c] w[c, h, v]. A sealed ``w`` is one
+    (c, heads * v) matmul, of which each head keeps its own columns."""
+    if not isinstance(w, SealedTensor):
+        return jnp.einsum("bshc,chv->bshv", x, w.astype(dt))
+    y = dense(x, w, "bshc,cgv->bshgv", dt)               # (b, s, h, h, v)
+    return jnp.moveaxis(jnp.diagonal(y, axis1=2, axis2=3), -1, 2)
+
+
+def mla_apply(cfg: ModelConfig, p, x, positions, latent, k_positions):
+    """Absorbed MLA over ``latent`` (b, t, rank + rope), the entries of the
+    keys at ``k_positions``: query head h reads [W_UK,h^T q_nope,h ;
+    rope(q_pe,h)] against every entry, one key "head" shared by all heads,
+    and its output is W_UV,h applied to the probability-weighted c_kv.
+    Softmax scale (nope + rope)^-1/2. Returns (b, s, d)."""
+    a, dt = cfg.mla, cdtype(cfg)
+    q = dense(x.astype(dt), p["wq"], "bsd,dhk->bshk", dt)
+    q_pe = apply_rope(q[..., a.nope_dim:], positions, cfg.rope_theta)
+    with jax.named_scope("mla_absorb"):
+        q_lat = _per_head_in(q[..., :a.nope_dim], p["wk_b"], dt)
+    qf = jnp.concatenate([q_lat, q_pe.astype(dt)], axis=-1)
+    mask = _attn_mask(positions, k_positions, 0)
+    if mask.ndim == 2:
+        mask = mask[None]
+    lat = latent.astype(dt)
+    with jax.named_scope("attention"):
+        scores = jnp.einsum("bshc,btc->bhst", qf, lat,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.where(mask[:, None], scores * cfg.head_dim ** -0.5,
+                           -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+        o_lat = jnp.einsum("bhst,btc->bshc", probs, lat[..., :a.kv_lora_rank])
+    with jax.named_scope("mla_absorb"):
+        o = _per_head_out(o_lat, p["wv_b"], dt)
+    return dense(o, p["wo"], "bshk,hkd->bsd", dt)
+
+
 def project_kv(cfg: ModelConfig, p, x, positions):
     """Just the k,v projections (+rope on k) — used when writing decode caches."""
     dt = cdtype(cfg)
@@ -531,3 +622,110 @@ def _moe_apply_block(cfg: ModelConfig, p, x, *, capacity_factor: float | None = 
     out = jnp.zeros((t, d), dt).at[tok_idx].add(weighted)
     out = constrain(out, "moe_tokens", None)
     return out.reshape(b, s, d), aux
+
+
+# --------------------------------------------------------------------------
+# dropless MoE over the experts this chip holds (DeepSeek-V3 layer)
+# --------------------------------------------------------------------------
+
+def init_moe_held(cfg: ModelConfig, key):
+    """Router over every routed expert with its correction bias, the held
+    experts' weights, and the shared experts as one MLP. The bias is drawn
+    from the key, so selection by score + bias differs from selection by
+    score."""
+    m, d = cfg.moe, cfg.d_model
+    f = m.d_expert or cfg.d_ff
+    ki, kg, ko = jax.random.split(key, 3)
+    kr, kb, ks = (jax.random.fold_in(key, i) for i in (7, 8, 9))
+    p = {
+        "router": (jax.random.normal(kr, (d, m.num_experts)) * d ** -0.5
+                   ).astype(jnp.float32),
+        "score_bias": (jax.random.normal(kb, (m.num_experts,)) * 0.1
+                       ).astype(jnp.float32),
+        "experts": {
+            "wi": (jax.random.normal(ki, (m.held, d, f)) * d ** -0.5
+                   ).astype(jnp.float32),
+            "wg": (jax.random.normal(kg, (m.held, d, f)) * d ** -0.5
+                   ).astype(jnp.float32),
+            "wo": (jax.random.normal(ko, (m.held, f, d)) * f ** -0.5
+                   ).astype(jnp.float32),
+        },
+    }
+    if m.d_shared:
+        p["shared"] = init_mlp(cfg.with_(d_ff=m.d_shared, moe=None), ks)
+    return p
+
+
+def moe_route(cfg: ModelConfig, p, x2d):
+    """DeepSeek-V3's top-k routing over every routed expert (noaux_tc with
+    one group), in f32 as published implementations do: the choice is the
+    top-k of sigmoid score + correction bias, and the weights are the
+    chosen (unbiased) scores, normalised over the k and scaled by
+    ``route_scale``. Returns (weights (t, k) f32, expert ids (t, k) i32)."""
+    m = cfg.moe
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + p["score_bias"], m.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * m.route_scale, idx
+
+
+def grouped_matmul(xs, w, dt):
+    """(E, T, K) x (E, K, N) -> (E, T, N) in ``dt``; a sealed ``w`` goes
+    still sealed into ``kernels.sealed_gmm``."""
+    if isinstance(w, SealedTensor):
+        return w.gmm(xs, compute_dtype=str(jnp.dtype(dt))).astype(dt)
+    return jnp.einsum("etk,ekn->etn", xs, w.astype(dt))
+
+
+def moe_held(cfg: ModelConfig, p, x, live=None, first: int = 0):
+    """Dropless MoE layer at one chip's share: routes every token over all
+    routed experts, computes the part of the result that the held experts
+    [first, first + held) give, and adds the shared experts.
+
+    The (token, expert) pairs that land on held experts are grouped into
+    one slab of T rows per held expert (T = tokens in the call, the most
+    one expert can receive, so no pair is ever dropped): slab row r of
+    expert e holds the r-th token routed to e, in token order. Each token's
+    output depends only on its own routes. Returns (out (b, s, d), routes
+    (2,) u32: pairs of ``live`` tokens landing on held experts, and all
+    their pairs)."""
+    m, dt = cfg.moe, cdtype(cfg)
+    b, s, d = x.shape
+    t = b * s
+    xb = x.reshape(t, d).astype(dt)
+    ex, e_h = p["experts"], m.held
+    with jax.named_scope("moe_route"):
+        w, idx = moe_route(cfg, p, xb)
+        loc = idx - first                                  # (t, k)
+        held = (loc >= 0) & (loc < e_h)
+        loc = jnp.where(held, loc, 0)
+        onehot = (loc[..., None] == jnp.arange(e_h)) & held[..., None]
+        pres = jnp.any(onehot, axis=1).astype(jnp.int32)   # (t, e_h)
+        rank = jnp.cumsum(pres, axis=0) - pres             # tokens before
+        slot = jnp.take_along_axis(rank, loc, axis=1)      # (t, k)
+        tok = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[:, None],
+                               loc.shape)
+        src = jnp.full((e_h, t), t, jnp.int32).at[
+            jnp.where(held, loc, e_h), slot].set(tok, mode="drop")
+        lv = (jnp.ones((t,), bool) if live is None
+              else jnp.broadcast_to(live, (b, s)).reshape(t))
+        routes = jnp.stack([jnp.sum(held & lv[:, None]),
+                            jnp.sum(lv) * m.top_k]).astype(jnp.uint32)
+    with jax.named_scope("moe_experts"):
+        xs = jnp.concatenate([xb, jnp.zeros((1, d), dt)])[src]  # (e_h, t, d)
+        a = act_fn(cfg.act)
+        h = (a(grouped_matmul(xs, ex["wg"], dt))
+             * grouped_matmul(xs, ex["wi"], dt))
+        y = grouped_matmul(h, ex["wo"], dt).reshape(e_h * t, d)
+        yr = y[loc * t + slot]                             # (t, k, d)
+        gate = jnp.where(held, w, 0.0)
+        out = jnp.einsum("tkd,tk->td", yr.astype(jnp.float32), gate)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            out = out + mlp_apply(cfg, p["shared"], xb[None]
+                                  )[0].astype(jnp.float32)
+    return out.astype(dt).reshape(b, s, d), routes

@@ -27,6 +27,8 @@ lives in ``serve/engine.py``; everything here is pure and jit-friendly.
 """
 from __future__ import annotations
 
+import functools
+import operator
 from typing import Optional
 
 import jax
@@ -44,75 +46,81 @@ from repro.core.sealed_store import CacheSeal
 
 def _dense_view(cfg: ModelConfig, seal: Optional[CacheSeal], pool_j,
                 tables, lengths, wc, pos_len=None):
-    """Gather one layer's blocks into the dense {"k","v","pos"} cache view
-    the decode attention consumes.
+    """Gather one layer's blocks into the dense cache view the decode
+    attention consumes: {"k","v","pos"}, or {"c","pos"} for MLA's latent
+    pool.
 
-    pool_j: one super-block slice {"k","v": (NB, wpb) u32, "lid": ()}.
-    tables: (B, MB) int32 pool block ids; lengths: (B,) int32; wc: (NB,) u32.
-    Returns ({"k","v","pos"}, ok): k/v (B, L, kv_heads, head_dim) with
-    L = MB * block_size, pos (B, L) int32 (INVALID_POS beyond each slot's
-    length), and ok (B,) bool — per-slot integrity verdict. When the seal
-    carries a MAC context, every *resident* gathered block (table entries
-    covering positions < length; uninitialized tail blocks are skipped) has
-    its Carter–Wegman tag recomputed over the gathered CIPHERTEXT — before
-    the unseal XOR, so the check authenticates exactly the HBM image — and
-    compared against the co-located ``mac_k``/``mac_v`` words. ok is all-True
-    when verification is off.
+    pool_j: one super-block slice {"k","v": (NB, wpb) u32, "lid": ()} (or
+    {"c": ...}). tables: (B, MB) int32 pool block ids; lengths: (B,) int32;
+    wc: (NB,) u32.
+    Returns (view, ok): k/v (B, L, kv_heads, head_dim) (c (B, L, width))
+    with L = MB * block_size, pos (B, L) int32 (INVALID_POS beyond each
+    slot's length), and ok (B,) bool — per-slot integrity verdict. When the
+    seal carries a MAC context, every *resident* gathered block (table
+    entries covering positions < length; uninitialized tail blocks are
+    skipped) has its Carter–Wegman tag recomputed over the gathered
+    CIPHERTEXT — before the unseal XOR, so the check authenticates exactly
+    the HBM image — and compared against the co-located ``mac_*`` words.
+    ok is all-True when verification is off.
 
     pos_len (B,) optionally extends the *position* validity past ``lengths``
-    for the chunked-prefill path, which splices the chunk's fresh K/V into
-    the zeroed tail of this view at their absolute positions — entry j is a
-    real key for j < pos_len even though only j < lengths came from the pool.
+    for the chunked-prefill path, which splices the chunk's fresh entries
+    into the zeroed tail of this view at their absolute positions — entry j
+    is a real key for j < pos_len even though only j < lengths came from
+    the pool.
     """
+    streams = MC.pool_streams(cfg)
     with jax.named_scope("kv_view"):
         b, mb = tables.shape
-        wpb = pool_j["k"].shape[-1]
+        wpb = pool_j[streams[0]].shape[-1]
         wpt = MC.kv_words_per_token(cfg)
         bs = wpb // wpt
         seq = mb * bs
         with jax.named_scope("kv_gather"):
-            kw = pool_j["k"][tables]               # (B, MB, wpb)
-            vw = pool_j["v"][tables]
+            words = {s: pool_j[s][tables] for s in streams}  # (B, MB, wpb)
             wcb = wc[tables] if seal is not None else None
         ok = jnp.ones((b,), bool)
         if seal is not None:
             if seal.mac is not None:
                 with jax.named_scope("kv_mac"):
-                    tk = seal.mac.tags(kw, tables, wcb, pool_j["lid"],
-                                       tweak=seal.nonce_k)
-                    tv = seal.mac.tags(vw, tables, wcb, pool_j["lid"],
-                                       tweak=seal.nonce_v)
+                    tags = [seal.mac.tags(words[s], tables, wcb,
+                                          pool_j["lid"], tweak=seal.nonce(s))
+                            for s in streams]
                     resident = (jnp.arange(mb, dtype=jnp.int32)[None, :]
                                 < ((lengths + bs - 1) // bs)[:, None])
-                    okb = ((tk == pool_j["mac_k"][tables])
-                           & (tv == pool_j["mac_v"][tables]))
+                    okb = functools.reduce(operator.and_, [
+                        t == pool_j[f"mac_{s}"][tables]
+                        for t, s in zip(tags, streams)])
                     ok = jnp.all((~resident) | okb, axis=1)
             with jax.named_scope("kv_unseal"):
-                kw = kw ^ KR.cache_block_otp(seal.key_words, seal.nonce_k,
-                                             tables, wcb, pool_j["lid"], wpb)
-                vw = vw ^ KR.cache_block_otp(seal.key_words, seal.nonce_v,
-                                             tables, wcb, pool_j["lid"], wpb)
+                for s in streams:
+                    words[s] = words[s] ^ KR.cache_block_otp(
+                        seal.key_words, seal.nonce(s), tables, wcb,
+                        pool_j["lid"], wpb)
         with jax.named_scope("kv_mask"):
             dt = jnp.dtype(cfg.dtype)
-            shape = (b, seq, cfg.num_kv_heads, cfg.head_dim)
-            k = MC.words_to_kv(kw, dt).reshape(shape)
-            v = MC.words_to_kv(vw, dt).reshape(shape)
+            shape = (b, seq) + MC.token_shape(cfg)
+            view = {s: MC.words_to_kv(words[s], dt).reshape(shape)
+                    for s in streams}
             pos = jnp.arange(seq, dtype=jnp.int32)[None, :]
             valid = pos < lengths[:, None]             # (B, L)
-            k = jnp.where(valid[..., None, None], k, 0)
-            v = jnp.where(valid[..., None, None], v, 0)
+            vmask = valid[(Ellipsis,) + (None,) * (len(shape) - 2)]
+            for s in streams:
+                view[s] = jnp.where(vmask, view[s], 0)
             vpos = valid if pos_len is None else pos < pos_len[:, None]
-            pos = jnp.where(vpos, pos, MC.INVALID_POS)
-        return {"k": k, "v": v, "pos": pos}, ok
+            view["pos"] = jnp.where(vpos, pos, MC.INVALID_POS)
+        return view, ok
 
 
 def decode_logits(cfg: ModelConfig, params, pools, tables, lengths, wc,
-                  tokens, seal: Optional[CacheSeal]):
+                  tokens, seal: Optional[CacheSeal], live=None):
     """One decode step for every slot at its own position.
 
     tokens: (B, 1) int32 (garbage for inactive slots — masked by lengths).
+    live: (B, 1) bool, the slots that decode (what a MoE layer counts).
     Returns (logits (B, V) f32, updates: per-position {"k_new","v_new"}
-    stacked (n_super, B, 1, kv_heads, head_dim), ok (B,) bool — the AND of
+    ({"c_new"} for MLA, and a MoE model's per-layer "routes" counters)
+    stacked (n_super, B, 1, ...), ok (B,) bool — the AND of
     every layer's cache-read integrity verdict; all-True unless the seal
     carries a MAC context).
     """
@@ -125,13 +133,15 @@ def decode_logits(cfg: ModelConfig, params, pools, tables, lengths, wc,
         for j, kind in enumerate(cfg.pattern):
             view, okj = _dense_view(cfg, seal, pool_slices[j], tables,
                                     lengths, wc)
+            if live is not None:
+                view["live"] = live
             h, up, _ = B.block_apply(cfg, kind, p_slices[j], h, positions,
                                      "decode", view)
             ups.append(up)
             oks.append(okj)
         return h, (tuple(ups), jnp.all(jnp.stack(oks), axis=0))
 
-    x, (updates, oks) = lax.scan(body, x, (params["blocks"], pools))
+    x, (updates, oks) = T.scan_layers(cfg, params, body, x, pools)
     x = L.apply_norm(cfg, params["final_norm"], x)
     logits = T._unembed(cfg, params, x)[:, 0]
     return logits, updates, jnp.all(oks, axis=0)
@@ -148,8 +158,8 @@ def chunk_logits(cfg: ModelConfig, params, pools, tables, lengths, wc,
     exact layout of a contiguous prefill, so a chunked prefill reproduces
     the one-shot ``prefill_logits`` bit-for-bit (given matching view
     widths). Returns (logits (B, V) at each row's last chunk token,
-    updates: per layer {"k_new","v_new"} stacked (n, B, C, kv_heads, hd)
-    for ``append_tokens`` to seal into the pools, ok (B,) bool — per-slot
+    updates: per layer {"k_new","v_new"} (or {"c_new"}) stacked (n, B, C,
+    ...) for ``append_tokens`` to seal into the pools, ok (B,) bool — per-slot
     cache-read integrity verdict across all layers).
     """
     x = T._embed(cfg, params, {"tokens": tokens})
@@ -170,7 +180,7 @@ def chunk_logits(cfg: ModelConfig, params, pools, tables, lengths, wc,
             oks.append(okj)
         return h, (tuple(ups), jnp.all(jnp.stack(oks), axis=0))
 
-    x, (updates, oks) = lax.scan(body, x, (params["blocks"], pools))
+    x, (updates, oks) = T.scan_layers(cfg, params, body, x, pools)
     x = L.apply_norm(cfg, params["final_norm"], x)
     idx = jnp.maximum(chunk_len - 1, 0)[:, None, None]
     last = jnp.take_along_axis(
@@ -181,7 +191,8 @@ def chunk_logits(cfg: ModelConfig, params, pools, tables, lengths, wc,
 
 def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
                   updates, tables, lengths, counts, wc):
-    """Splice each row's ``counts[i]`` new K/V tokens into its blocks at
+    """Splice each row's ``counts[i]`` new K/V tokens (MLA: latent
+    entries) into its blocks at
     positions [lengths[i], lengths[i] + counts[i]) — the unified write path
     for the decode append (C == 1) and the chunked prefill (C == chunk).
 
@@ -193,6 +204,7 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
     (out-of-bounds) indices, so masked slots cost no writes and no counter
     bumps. Returns (pools, wc).
     """
+    streams = MC.pool_streams(cfg)
     with jax.named_scope("kv_append"):
         wpt = MC.kv_words_per_token(cfg)
         b, mb = tables.shape
@@ -201,9 +213,9 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
         wc_out = wc
         for j in range(len(cfg.pattern)):
             pj, uj = pools[j], updates[j]
-            wpb = pj["k"].shape[-1]
+            wpb = pj[streams[0]].shape[-1]
             bs = wpb // wpt
-            c = uj["k_new"].shape[2]
+            c = uj[f"{streams[0]}_new"].shape[2]
             nspan = 1 + (c + bs - 2) // bs     # blocks a chunk write can span
             lid = pj["lid"]
             n = lid.shape[0]
@@ -251,12 +263,12 @@ def append_tokens(cfg: ModelConfig, seal: Optional[CacheSeal], pools,
                     mac_words = mac_words.at[:, tgt].set(tags, mode="drop")
                 return pool_words.at[:, tgt].set(out, mode="drop"), mac_words
 
-            nk, nmk = splice(pj["k"], pj["mac_k"], uj["k_new"],
-                             seal.nonce_k if seal is not None else None)
-            nv, nmv = splice(pj["v"], pj["mac_v"], uj["v_new"],
-                             seal.nonce_v if seal is not None else None)
-            new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                              "lid": lid})
+            new = {}
+            for s in streams:
+                new[s], new[f"mac_{s}"] = splice(
+                    pj[s], pj[f"mac_{s}"], uj[f"{s}_new"],
+                    seal.nonce(s) if seal is not None else None)
+            new_pools.append(dict(new, lid=lid))
             if j == 0:
                 tgt = jnp.where(touched, pb, nb)
                 wc_out = wc.at[tgt].add(jnp.uint32(1), mode="drop")
@@ -282,8 +294,9 @@ def copy_blocks(cfg: ModelConfig, seal: Optional[CacheSeal], pools, wc,
         tgt = jnp.where(mask, dst, nb)                 # pads -> dropped
         new_pools = []
         oks = []
+        streams = MC.pool_streams(cfg)
         for pj in pools:
-            wpb = pj["k"].shape[-1]
+            wpb = pj[streams[0]].shape[-1]
             lid = pj["lid"]
 
             def copy(pool_words, mac_words, nonce):
@@ -307,13 +320,14 @@ def copy_blocks(cfg: ModelConfig, seal: Optional[CacheSeal], pools, wc,
                 return (pool_words.at[:, tgt].set(blk, mode="drop"),
                         mac_words, ok)
 
-            nk, nmk, ok_k = copy(pj["k"], pj["mac_k"],
-                                 seal.nonce_k if seal is not None else None)
-            nv, nmv, ok_v = copy(pj["v"], pj["mac_v"],
-                                 seal.nonce_v if seal is not None else None)
-            new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                              "lid": lid})
-            oks.append(ok_k & ok_v)
+            new, ok = {}, []
+            for s in streams:
+                new[s], new[f"mac_{s}"], ok_s = copy(
+                    pj[s], pj[f"mac_{s}"],
+                    seal.nonce(s) if seal is not None else None)
+                ok.append(ok_s)
+            new_pools.append(dict(new, lid=lid))
+            oks.append(functools.reduce(operator.and_, ok))
         return (tuple(new_pools), wc.at[tgt].add(jnp.uint32(1), mode="drop"),
                 jnp.all(jnp.stack(oks)))
 
@@ -340,19 +354,21 @@ def prefill_write(cfg: ModelConfig, seal: Optional[CacheSeal], pools, cache,
                   block_tables, wc):
     """Seal a prefill's contiguous cache into pool blocks.
 
-    cache: per pattern position {"k","v": (n, A, S_bucket, h, d)}.
+    cache: per pattern position {"k","v": (n, A, S_bucket, h, d)} (MLA:
+    {"c": (n, A, S_bucket, width)}).
     block_tables: (A, S_bucket // bs) pool ids — the host bumps the write
     counters of these blocks *before* the call, so the seal uses the passed
     ``wc`` directly. Dummy admission rows carry a zeroed table row and land
     on the scratch block.
     """
     wpt = MC.kv_words_per_token(cfg)
+    streams = MC.pool_streams(cfg)
     a, nblk = block_tables.shape
     new_pools = []
     for j in range(len(cfg.pattern)):
         pj, cj = pools[j], cache[j]
-        wpb = pj["k"].shape[-1]
-        n, sb = cj["k"].shape[0], cj["k"].shape[2]
+        wpb = pj[streams[0]].shape[-1]
+        n, sb = cj[streams[0]].shape[0], cj[streams[0]].shape[2]
         assert sb * wpt == nblk * wpb, (sb, wpt, nblk, wpb)
 
         def write(pool_words, mac_words, kv, nonce):
@@ -369,10 +385,10 @@ def prefill_write(cfg: ModelConfig, seal: Optional[CacheSeal], pools, cache,
                     mac_words = mac_words.at[:, block_tables].set(tags)
             return pool_words.at[:, block_tables].set(w), mac_words
 
-        nk, nmk = write(pj["k"], pj["mac_k"], cj["k"],
-                        seal.nonce_k if seal is not None else None)
-        nv, nmv = write(pj["v"], pj["mac_v"], cj["v"],
-                        seal.nonce_v if seal is not None else None)
-        new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                          "lid": pj["lid"]})
+        new = {}
+        for s in streams:
+            new[s], new[f"mac_{s}"] = write(
+                pj[s], pj[f"mac_{s}"], cj[s],
+                seal.nonce(s) if seal is not None else None)
+        new_pools.append(dict(new, lid=pj["lid"]))
     return tuple(new_pools)

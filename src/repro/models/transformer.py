@@ -39,12 +39,21 @@ def init_params(cfg: ModelConfig, key):
     if not cfg.tie_embeddings:
         params["head"] = {"w": (jax.random.normal(kh, (cfg.d_model, cfg.vocab_size))
                                 * cfg.d_model ** -0.5).astype(jnp.float32)}
-    per_position = []
-    for j, kind in enumerate(cfg.pattern):
-        stacked = [B.init_block(cfg, kind, jax.random.fold_in(kb, i * 131 + j))
-                   for i in range(n)]
-        per_position.append(jax.tree.map(lambda *xs: jnp.stack(xs), *stacked))
-    params["blocks"] = tuple(per_position)
+    def stack(layers, dense=False):
+        per_position = []
+        for j, kind in enumerate(cfg.pattern):
+            stacked = [B.init_block(cfg, kind,
+                                    jax.random.fold_in(kb, i * 131 + j),
+                                    dense=dense)
+                       for i in layers]
+            per_position.append(jax.tree.map(lambda *xs: jnp.stack(xs),
+                                             *stacked))
+        return tuple(per_position)
+
+    nd = cfg.first_dense
+    if nd:
+        params["dense_blocks"] = stack(range(nd), dense=True)
+    params["blocks"] = stack(range(nd, n))
     return params
 
 
@@ -79,15 +88,35 @@ def _unembed(cfg: ModelConfig, params, x):
     return L.softcap(logits, cfg.logit_softcap)
 
 
+def scan_layers(cfg: ModelConfig, params, body, carry, per_layer=None):
+    """``lax.scan`` of ``body(carry, (layer params, per-layer slice))`` over
+    the super-blocks; ``per_layer`` is a pytree stacked over all of them (a
+    cache, the paged pools) or None. A model with leading dense layers runs
+    their stack first, then the main one; each scan indexes its layers'
+    slices out of ``per_layer`` in place, and the per-layer outputs are
+    joined in layer order."""
+    if not cfg.first_dense:
+        return lax.scan(body, carry, (params["blocks"], per_layer))
+    at = lambda i: jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), per_layer)
+    ys = []
+    for stack, layers in ((params["dense_blocks"], (0, cfg.first_dense)),
+                          (params["blocks"],
+                           (cfg.first_dense, cfg.n_superblocks()))):
+        carry, y = lax.scan(lambda c, xs: body(c, (xs[0], at(xs[1]))),
+                            carry, (stack, jnp.arange(*layers)))
+        ys.append(y)
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+
+
 def _run_layers(cfg: ModelConfig, params, x, positions, mode, cache, remat: str):
     """Scan the super-block stack. Returns (x, new_cache, aux)."""
 
     def body(carry, xs):
         h, aux = carry
-        if mode == "decode" or mode == "prefill":
-            p_slices, c_slices = xs
-        else:
-            p_slices, c_slices = xs, tuple(None for _ in cfg.pattern)
+        p_slices, c_slices = xs
+        if c_slices is None:
+            c_slices = tuple(None for _ in cfg.pattern)
         new_caches = []
         for j, kind in enumerate(cfg.pattern):
             cj = c_slices[j] if c_slices[j] is not None else None
@@ -104,15 +133,10 @@ def _run_layers(cfg: ModelConfig, params, x, positions, mode, cache, remat: str)
             body, prevent_cse=False,
             policy=jax.checkpoint_policies.save_only_these_names())
 
-    if mode == "decode":
-        xs = (params["blocks"], cache)
-    elif mode == "prefill":
-        # prefill consumes an (empty) cache pytree to define slot shapes
-        xs = (params["blocks"], cache)
-    else:
-        xs = params["blocks"]
-
-    (x, aux), ys = lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    # prefill consumes an (empty) cache pytree to define slot shapes
+    per_layer = cache if mode in ("decode", "prefill") else None
+    (x, aux), ys = scan_layers(cfg, params, body,
+                               (x, jnp.zeros((), jnp.float32)), per_layer)
     new_cache = ys if mode in ("prefill", "decode") else None
     return x, new_cache, aux
 
@@ -171,7 +195,11 @@ def apply_cache_updates(cfg: ModelConfig, cache, updates, pos):
     new = []
     for j, kind in enumerate(cfg.pattern):
         cj, uj = cache[j], updates[j]
-        if kind in ("attn", "local_attn"):
+        if kind in ("attn", "local_attn") and cfg.mla is not None:
+            slot = pos % cj["c"].shape[2]
+            new.append({"c": cj["c"].at[:, :, slot].set(uj["c_new"][:, :, 0]),
+                        "pos": cj["pos"].at[:, slot].set(pos)})
+        elif kind in ("attn", "local_attn"):
             cache_len = cj["k"].shape[2]
             slot = pos % cache_len
             new.append({

@@ -71,6 +71,12 @@ def _jit(fn, donate):
     return jax.jit(fn, donate_argnums=donate)
 
 
+def _kv_bytes_per_token(cfg: ModelConfig) -> int:
+    """Cache bytes one token holds in one layer: K and V, or MLA's one
+    latent entry."""
+    return len(MC.pool_streams(cfg)) * 4 * MC.kv_words_per_token(cfg)
+
+
 class ServeEngine:
     """Continuous batcher over the paged, sealed KV cache.
 
@@ -192,12 +198,11 @@ class ServeEngine:
         self._done: List[Request] = []
 
         kv_pt = 0 if seal_cache else (
-            2 * cfg.n_superblocks() * len(cfg.pattern) * s * self.max_len
-            * cfg.num_kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize)
+            cfg.num_layers * s * self.max_len * _kv_bytes_per_token(cfg))
         w_pt = (self.sealed.plaintext_bytes_materialized() if self.sealed
                 else sum(int(np.prod(x.shape)) * x.dtype.itemsize
                          for x in jax.tree.leaves(params)))
-        self.stats = {
+        self._stats = {
             "prefills": 0, "prefill_chunks": 0, "decode_steps": 0,
             "tokens": 0, "cow_copies": 0,
             "mac_checks": 0, "mac_failures": 0, "retries": 0,
@@ -210,8 +215,21 @@ class ServeEngine:
             "kv_plaintext_bytes_per_step": kv_pt,
             "plaintext_bytes_per_step": w_pt + kv_pt,
         }
+        if cfg.moe is not None and cfg.moe.router == "sigmoid_bias":
+            self._stats.update(moe_routes_held=0, moe_routes=0)
 
     # -------------------------------------------------- public API
+
+    @property
+    def stats(self) -> Dict[str, int]:
+        """The engine's counters. A MoE model's route counters
+        (``moe_routes_held``: pairs of real tokens that landed on the held
+        experts; ``moe_routes``: all their pairs) accumulate on the device
+        and are read here, never per tick."""
+        if "moe_routes" in self._stats:
+            held, total = (int(v) for v in np.asarray(self._state.routes))
+            self._stats.update(moe_routes_held=held, moe_routes=total)
+        return self._stats
 
     def submit(self, prompt, max_tokens: int = 32, eos: int = -1,
                temperature: float = 0.0, top_k: int = 0,
@@ -277,12 +295,12 @@ class ServeEngine:
         self._verify_weights()          # fail-stop sweep at drain entry
         steps = 0
         while self.busy:
-            before = (len(self.queue), self.stats["decode_steps"],
-                      self.stats["prefills"])
+            before = (len(self.queue), self._stats["decode_steps"],
+                      self._stats["prefills"])
             t0 = time.perf_counter()
             self.step()
-            after = (len(self.queue), self.stats["decode_steps"],
-                     self.stats["prefills"])
+            after = (len(self.queue), self._stats["decode_steps"],
+                     self._stats["prefills"])
             assert after != before, "scheduler made no progress"
             steps += 1
             if self.watchdog is not None:
@@ -366,10 +384,10 @@ class ServeEngine:
                 if partial is not None:
                     cow_pairs.append((partial[0], priv[0]))
                     cow_slots.append(slot)
-                    self.stats["cow_copies"] += 1
-                self.stats["shared_prefix_blocks"] += (
+                    self._stats["cow_copies"] += 1
+                self._stats["shared_prefix_blocks"] += (
                     len(full) + (1 if partial else 0))
-                self.stats["shared_prefix_tokens"] += n_shared
+                self._stats["shared_prefix_tokens"] += n_shared
                 batch.append((slot, r, table, n_shared, held))
             if not batch:
                 return
@@ -403,7 +421,7 @@ class ServeEngine:
                     self._pools, self._state, jnp.asarray(src),
                     jnp.asarray(dst), jnp.asarray(msk))
                 if self.verify and self.seal_cache:
-                    self.stats["mac_checks"] += len(cow_pairs)
+                    self._stats["mac_checks"] += len(cow_pairs)
                     if not bool(cok):
                         # a shared source block failed its MAC: the copy
                         # would launder tampered content under a fresh tag,
@@ -441,8 +459,8 @@ class ServeEngine:
             tok, cok, self._state, self._pools = self._chunk(
                 self._params_arg, self._pools, self._state, jnp.asarray(sl),
                 jnp.asarray(toks), jnp.asarray(cl), jnp.asarray(fin))
-            self.stats["prefills"] += 1
-            self.stats["prefill_chunks"] += len(rows)
+            self._stats["prefills"] += 1
+            self._stats["prefill_chunks"] += len(rows)
             with _span("serve.chunk.readback"):
                 tok = np.asarray(tok)
         cok_h = self._check_integrity(cok, len(rows))
@@ -470,7 +488,7 @@ class ServeEngine:
             self._counts[slot] = 1
             self._last_tok[slot] = nt
             r.out.append(nt)
-            self.stats["tokens"] += 1
+            self._stats["tokens"] += 1
             if len(r.out) >= self._mt_eff(r) or nt == r.eos:
                 finished.append(slot)
         if failed:
@@ -497,7 +515,7 @@ class ServeEngine:
               "blocks_reserved": sum(len(b) for b in self._slot_blocks),
               "running": len(running)}
         for k in ("gathered", "resident", "reserved"):
-            self.stats[f"kv_blocks_{k}"] += kv[f"blocks_{k}"]
+            self._stats[f"kv_blocks_{k}"] += kv[f"blocks_{k}"]
         return kv
 
     def _decode_tick(self):
@@ -506,7 +524,7 @@ class ServeEngine:
         with _span("serve.decode", **self._kv_blocks(running)):
             tok, cok, self._state, self._pools = self._decode(
                 *self._decode_args())
-            self.stats["decode_steps"] += 1
+            self._stats["decode_steps"] += 1
             with _span("serve.decode.readback"):
                 tok = np.asarray(tok)          # the ONLY d2h copy per tick
         cok_h = self._check_integrity(cok, len(running))
@@ -528,7 +546,7 @@ class ServeEngine:
             nt = int(tok[slot])
             self._last_tok[slot] = nt
             r.out.append(nt)
-            self.stats["tokens"] += 1
+            self._stats["tokens"] += 1
             if len(r.out) >= self._mt_eff(r) or nt == r.eos:
                 finished.append(slot)
         if failed:
@@ -548,11 +566,11 @@ class ServeEngine:
         self._wswept = True
         if not (self.verify and self._has_wverify):
             return
-        self.stats["mac_checks"] += 1
+        self._stats["mac_checks"] += 1
         with _span("serve.verify_weights"):
             intact = bool(self._wverify(self._params_arg))
         if not intact:
-            self.stats["mac_failures"] += 1
+            self._stats["mac_failures"] += 1
             raise SealedIntegrityError(
                 "weights", "sealed weight image failed its MAC sweep — "
                 "fail-stop, the model is not trustworthy")
@@ -564,7 +582,7 @@ class ServeEngine:
         Weight integrity is handled separately in ``_verify_weights``."""
         if not self.verify:
             return None
-        self.stats["mac_checks"] += n_checked
+        self._stats["mac_checks"] += n_checked
         with _span("serve.integrity"):
             return np.asarray(cok)
 
@@ -579,7 +597,7 @@ class ServeEngine:
         that passed their check are untouched and decode bit-identically
         through the recovery."""
         with _span("serve.integrity"):
-            self.stats["mac_failures"] += len(slots)
+            self._stats["mac_failures"] += len(slots)
             victims = [self._active[s] for s in slots]
             if self._registry is not None:
                 bad = [b for s in slots for b in self._slot_blocks[s]]
@@ -596,7 +614,7 @@ class ServeEngine:
                     continue
                 r.retries += 1
                 r.out = []
-                self.stats["retries"] += 1
+                self._stats["retries"] += 1
                 self.queue.insert(0, r)
 
     def _evict_slots(self, slots: List[int], complete: bool = True):
@@ -675,9 +693,8 @@ class GroupServeEngine:
         # same weights+KV split the continuous engine reports: the group
         # engine's contiguous cache is never sealed, so its KV image is
         # plaintext in full
-        kv_pt = (2 * cfg.n_superblocks() * len(cfg.pattern) * batch_slots
-                 * max_len * cfg.num_kv_heads * cfg.head_dim
-                 * jnp.dtype(cfg.dtype).itemsize)
+        kv_pt = cfg.num_layers * batch_slots * max_len * \
+            _kv_bytes_per_token(cfg)
         w_pt = (self.sealed.plaintext_bytes_materialized() if self.sealed
                 else sum(int(np.prod(x.shape)) * x.dtype.itemsize
                          for x in jax.tree.leaves(params)))

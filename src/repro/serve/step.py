@@ -42,6 +42,9 @@ class SchedState:
     counts (S,) i32      tokens generated so far (PRNG stream index)
     key_data (S, 2) u32  per-request PRNG key
     temp/topk/topp       per-request sampling params
+    routes (2,) u32      MoE counters since start: (token, expert) pairs of
+                         real tokens that landed on held experts, and all
+                         their pairs (zero for dense models)
     """
     tables: jax.Array
     lengths: jax.Array
@@ -53,6 +56,7 @@ class SchedState:
     temp: jax.Array
     topk: jax.Array
     topp: jax.Array
+    routes: jax.Array
 
 
 def sched_init(slots: int, max_blocks: int, num_blocks: int) -> SchedState:
@@ -68,7 +72,17 @@ def sched_init(slots: int, max_blocks: int, num_blocks: int) -> SchedState:
         temp=jnp.zeros((s,), jnp.float32),
         topk=jnp.zeros((s,), jnp.int32),
         topp=jnp.ones((s,), jnp.float32),
+        routes=jnp.zeros((2,), jnp.uint32),
     )
+
+
+def _routes(state: SchedState, updates):
+    """``state.routes`` plus the per-layer counters a MoE model's update
+    records carry (unchanged for other models)."""
+    per_layer = [u["routes"] for u in updates if "routes" in u]
+    if not per_layer:
+        return state.routes
+    return state.routes + sum(jnp.sum(r, axis=0) for r in per_layer)
 
 
 def make_admit():
@@ -163,6 +177,7 @@ def make_chunk_step(cfg: ModelConfig, materialize, cache_seal):
         state = dataclasses.replace(
             state,
             wc=wc,
+            routes=_routes(state, updates),
             lengths=state.lengths.at[slot_ids].add(chunk_len, mode="drop"),
             run=state.run.at[slot_ids].set(is_final, mode="drop"),
             counts=state.counts.at[slot_ids].set(
@@ -186,7 +201,8 @@ def make_decode_tick(cfg: ModelConfig, materialize, cache_seal):
         tokens = state.last_tok[:, None]
         logits, updates, cok = PG.decode_logits(cfg, params, pools,
                                                 state.tables, state.lengths,
-                                                state.wc, tokens, cache_seal)
+                                                state.wc, tokens, cache_seal,
+                                                live=state.run[:, None])
         cnt = state.run.astype(jnp.int32)
         pools, wc = PG.append_tokens(cfg, cache_seal, pools, updates,
                                      state.tables, state.lengths, cnt,
@@ -197,7 +213,7 @@ def make_decode_tick(cfg: ModelConfig, materialize, cache_seal):
         tok = jnp.where(state.run, tok, state.last_tok)
         cok = cok | ~state.run            # only running slots can fail
         state = dataclasses.replace(
-            state, wc=wc,
+            state, wc=wc, routes=_routes(state, updates),
             lengths=state.lengths + cnt,
             counts=state.counts + cnt,
             last_tok=tok,

@@ -4,8 +4,10 @@ Nothing runs: the TPU compiler, which is installed with JAX, compiles each
 kernel for a v5e chip that is described through
 ``jax.experimental.topologies`` and not attached. This catches what
 interpret mode cannot (block shapes Mosaic refuses, relayouts it cannot
-lower, scalars outside SMEM) at the widths of internlm2_1_8b, and keeps an
-element-granular gather out of the cache's write path. The topology
+lower, scalars outside SMEM) at the widths of internlm2_1_8b and of
+Moonlight's chip share, keeps an element-granular gather out of the
+cache's write path, and keeps the plaintext of the held experts out of the
+optimised program of a MoE layer's decode. The topology
 is described in a fixture, never at import, so every pytest worker collects
 the same tests and only the one that runs this file loads the TPU library.
 """
@@ -14,15 +16,23 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.config import SealConfig
 from repro.configs import get_config
+from repro.core import coloe as CL
+from repro.core import plan as P
 from repro.core import sealed_store as SS
+from repro.core.sealed_tensor import SealedTensor, SealMeta
 from repro.kernels import chacha20 as CC
 from repro.kernels import flash_attention as FA
+from repro.kernels import ops
+from repro.kernels import sealed_gmm as SG
 from repro.kernels import sealed_matmul as SM
 from repro.models import cache as MC
+from repro.models import layers as L
 from repro.models import paged as PG
 
 
@@ -146,3 +156,84 @@ def test_append_tokens_has_no_word_gather_on_v5e(one_chip, seal, c, b):
         S(jax.ShapeDtypeStruct((b, 2 * wpb), jnp.uint32)),
         i32(b, 2 * wpb)).compile().as_text()
     assert _word_gathers(text, wpb)
+
+
+@pytest.mark.parametrize("t,k,n", [(32, 2048, 1408), (32, 1408, 2048),
+                                   (256, 2048, 1408)])
+def test_sealed_gmm_compiles_for_v5e(one_chip, t, k, n):
+    """Moonlight's held experts (8 x 2048 x 1408): a decode tick's 32 tokens
+    and a chunk step's 256."""
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(x, w, mask, key, nonce, wc):
+        return SG.sealed_gmm(x, w, mask, key, nonce, wc, bk=128, bn=128,
+                             interpret=False, compute_dtype="bfloat16")
+
+    _compile(fn, S((8, t, k), jnp.bfloat16), S((8, k, n), jnp.uint32),
+             S((8, k), jnp.bool_), S((8,), jnp.uint32), S((3,), jnp.uint32),
+             S((8,), jnp.uint32))
+
+
+def _spec_sealed(tree, seal, sharding):
+    """The sealed image ``seal_params`` makes of ``tree``, as shapes: each
+    leaf in the layout ``tile_geometry`` gives it (tiles, or ColoE's line
+    records where it gives none), for a compile against a described chip.
+    Returns the materialisation the serving graph runs on it."""
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    tensors = {}
+    for kp, leaf in flat:
+        pt = P._path_tuple(kp)
+        g = SS.tile_geometry(pt, leaf.shape, leaf.dtype, seal)
+        if g is None:
+            words = int(np.prod(leaf.shape))
+            meta = SealMeta(scheme="coloe", layout="lines", dtype="float32",
+                            nonce=(1, 2), shape=tuple(leaf.shape),
+                            orig_len=words)
+            st = SealedTensor(S((-(-words // CL.WORDS_PER_LINE),
+                                 CL.COLOE_LINE_WORDS), jnp.uint32),
+                              None, None, None, None, meta)
+        else:
+            lead = leaf.shape[:g.n_batch]
+            meta = SealMeta(scheme="coloe", layout="tiles", dtype="float32",
+                            nonce=(3, 5, 7), shape=tuple(leaf.shape),
+                            n_batch=g.n_batch, k_ndim=g.k_ndim,
+                            n_out=g.n_out, bk=g.bk, bn=g.bn, fused=g.fused)
+            st = SealedTensor(S(leaf.shape, jnp.uint32), None,
+                              S(lead + (g.k,), jnp.bool_),
+                              S(lead + (8,), jnp.uint32),
+                              S(lead, jnp.uint32), meta)
+        tensors["/".join(pt)] = st
+    plans = dict.fromkeys(tensors)
+    return tensors, lambda t: SS.fused_params(
+        SS.SealedParams(t, plans, treedef, seal), bytes(range(32)))
+
+
+_PLAIN_EXPERTS = re.compile(r"(?:f32|bf16)\[(?:1,)?8,(?:2048,1408|1408,2048)\]")
+
+
+def test_moe_layer_decode_keeps_the_experts_sealed_on_v5e(one_chip,
+                                                          monkeypatch):
+    """One Moonlight MoE layer's decode at the chip share's widths (d 2048,
+    8 held experts of width 1408, 32 tokens), its weights as the sealed
+    store holds them: the held experts reach ``sealed_gmm`` still sealed,
+    so the optimised program holds no plaintext (8, 2048, 1408) array."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = get_config("moonlight_16b_a3b_ep8")
+    seal = SealConfig(mode="coloe", smart_ratio=0.5, verify=True)
+    layer = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a[None], L.init_moe_held(cfg, jax.random.key(0))))
+    tensors, materialize = _spec_sealed({"mlp": layer}, seal, one_chip)
+    x = jax.ShapeDtypeStruct((32, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fn(t, x):
+        def body(h, p):
+            out, _ = L.moe_held(cfg, p["mlp"], h)
+            return out, None
+        return jax.lax.scan(body, x, materialize(t))[0]
+
+    text = jax.jit(fn).lower(tensors, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _PLAIN_EXPERTS.findall(text) == []
