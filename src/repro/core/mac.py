@@ -113,6 +113,12 @@ def uhash(keys, words):
     # < 2^32 and the high-15 partial sum stays < 2^31 — both exact in u32
     lo = jnp.sum(terms & jnp.uint32(0xFFFF), axis=-1, dtype=jnp.uint32)
     hi = jnp.sum(terms >> 16, axis=-1, dtype=jnp.uint32)
+    return combine_halves(lo, hi)
+
+
+def combine_halves(lo, hi):
+    """The hash from the sums of its terms' low 16 bits (``lo``) and high
+    15 bits (``hi``): (hi * 2^16 + lo) mod P31, in u32 ops."""
     hi = _fold(hi)
     hi_m = _fold((hi >> 15) + ((hi & jnp.uint32(0x7FFF)) << 16))
     return _fold(hi_m + _fold(lo))
@@ -176,8 +182,13 @@ class MacContext:
         ``ct_words``: (..., W) u32; addrs/wcs/lids broadcast to (...,)."""
         ct = jnp.asarray(ct_words, jnp.uint32)
         tag = uhash(self.hash_keys(ct.shape[-1]), ct)
+        return tag ^ self.pads(addrs, wcs, lids, tweak)
+
+    def pads(self, addrs, wcs, lids=0, tweak=(0, 0, 0)):
+        """The Wegman–Carter pads of ``tags``: a stored tag XOR its pad is
+        the hash a verifier recomputes over the ciphertext."""
         n3 = tuple(int(a) ^ int(b) for a, b in zip(self.nonce3, tweak))
-        return tag ^ mac_pads(self.key_words, n3, addrs, wcs, lids)
+        return mac_pads(self.key_words, n3, addrs, wcs, lids)
 
 
 def mac_context(key_bytes: bytes, domain: str) -> MacContext:

@@ -143,7 +143,8 @@ class TamperInjector(FaultInjectionHook):
         block = int(engine._tables[self.slot, bi])
 
         def flip(arr):
-            arr[self.layer, block, self.word] ^= np.uint32(1 << self.bit)
+            words = arr.reshape(arr.shape[:2] + (-1,))     # a view
+            words[self.layer, block, self.word] ^= np.uint32(1 << self.bit)
 
         self._mutate(engine, 0, "k", flip)
         self._record(engine, block, layer=self.layer, word=self.word,

@@ -43,17 +43,24 @@ def _qr(a, b, c, d):
     return a, b, c, d
 
 
+def _double_round(x):
+    """x: list of 16 (T,) vectors -> after one column and one diagonal
+    round."""
+    x[0], x[4], x[8], x[12] = _qr(x[0], x[4], x[8], x[12])
+    x[1], x[5], x[9], x[13] = _qr(x[1], x[5], x[9], x[13])
+    x[2], x[6], x[10], x[14] = _qr(x[2], x[6], x[10], x[14])
+    x[3], x[7], x[11], x[15] = _qr(x[3], x[7], x[11], x[15])
+    x[0], x[5], x[10], x[15] = _qr(x[0], x[5], x[10], x[15])
+    x[1], x[6], x[11], x[12] = _qr(x[1], x[6], x[11], x[12])
+    x[2], x[7], x[8], x[13] = _qr(x[2], x[7], x[8], x[13])
+    x[3], x[4], x[9], x[14] = _qr(x[3], x[4], x[9], x[14])
+    return x
+
+
 def _chacha_rounds(x):
     """x: list of 16 (T,) vectors -> after 20 rounds (pre-add)."""
     for _ in range(10):
-        x[0], x[4], x[8], x[12] = _qr(x[0], x[4], x[8], x[12])
-        x[1], x[5], x[9], x[13] = _qr(x[1], x[5], x[9], x[13])
-        x[2], x[6], x[10], x[14] = _qr(x[2], x[6], x[10], x[14])
-        x[3], x[7], x[11], x[15] = _qr(x[3], x[7], x[11], x[15])
-        x[0], x[5], x[10], x[15] = _qr(x[0], x[5], x[10], x[15])
-        x[1], x[6], x[11], x[12] = _qr(x[1], x[6], x[11], x[12])
-        x[2], x[7], x[8], x[13] = _qr(x[2], x[7], x[8], x[13])
-        x[3], x[4], x[9], x[14] = _qr(x[3], x[4], x[9], x[14])
+        x = _double_round(x)
     return x
 
 

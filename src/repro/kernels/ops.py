@@ -18,6 +18,7 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as _ref
 from repro.kernels import sealed_gmm as _sg
 from repro.kernels import sealed_matmul as _sm
+from repro.kernels import sealed_paged_attention as _spa
 
 
 def _default_interpret() -> bool:
@@ -90,6 +91,49 @@ def flash_attention(q, k, v, *, scale: float, softcap: float = 0.0,
     return _fa.flash_attention(q, k, v, scale=scale, softcap=softcap,
                                window=window, bq=bq, bkv=bkv,
                                interpret=interpret)
+
+
+def paged_attention(q, k_pool, v_pool, tables, lengths, meta, *,
+                    block_size: int, scale: float, softcap: float = 0.0,
+                    wcb=None, expect=None, hash_keys=None, interpret=None):
+    """Attention of q (B, C, H, D) over each row's sealed pool prefix
+    ``[0, lengths)`` (``kernels.sealed_paged_attention``). The pools are
+    (n, NB, rows, lanes) u32 with the K/V dtype of q; meta, wcb, expect and
+    hash_keys as the kernel takes them. GQA: query head h reads kv head
+    ``h // (H / kv_heads)``.
+
+    Lays each query head's words out in the lanes its kv head takes in a
+    pool row (even and odd elements apart for a 2-byte dtype), and takes
+    each head's output back out of the kernel's rows. Returns (acc
+    (B, C, H, D) f32 unnormalised, m (B, C, H) running max, l (B, C, H)
+    denominator, bad (B,) bool)."""
+    interpret = _default_interpret() if interpret is None else interpret
+    b, c, h, d = q.shape
+    rows, lanes = k_pool.shape[-2:]
+    item = q.dtype.itemsize
+    dw = d * item // 4                         # words of one head
+    hkv = rows * lanes // block_size // dw
+    assert lanes % dw == 0 and h % hkv == 0, (k_pool.shape, q.shape)
+    kvh = np.arange(h) // (h // hkv)
+    slot = (kvh * dw) % lanes // dw            # head's lanes in its row
+    quarter = (kvh * dw) // lanes              # its row of the token
+    parts = [q[..., 0::2], q[..., 1::2]] if item == 2 else [q]
+    onehot = jnp.asarray(slot[:, None] == np.arange(lanes // dw))
+    qk = jnp.stack([jnp.where(onehot[:, :, None], p_[..., None, :], 0)
+                    for p_ in parts], axis=1)  # (B, parts, C, H, slots, dw)
+    qk = qk.reshape(b, len(parts), c * h, lanes)
+    acc, m, l, bad = _spa.sealed_paged_attention(
+        qk, jnp.asarray(np.tile(quarter, c)[:, None], jnp.int32), k_pool,
+        v_pool, lengths, tables, meta, wcb, expect, hash_keys,
+        block_size=block_size, kv_dtype=str(q.dtype), scale=scale,
+        softcap=softcap, interpret=interpret)
+    acc = acc.reshape(b, len(parts), c, h, lanes // dw, dw)
+    acc = jnp.take_along_axis(
+        acc, jnp.asarray(slot)[None, None, None, :, None, None],
+        axis=4)[:, :, :, :, 0]                 # (B, parts, C, H, dw)
+    acc = jnp.stack([acc[:, 0], acc[:, 1]], axis=-1) if item == 2 else acc[:, 0]
+    return (acc.reshape(b, c, h, d), m.reshape(b, c, h), l.reshape(b, c, h),
+            bad)
 
 
 def decrypt_then_matmul(x, w_ct, row_mask, key_words, nonce_words,

@@ -80,15 +80,20 @@ def tile_xor(words, key_words, nonce_words, bk: int, bn: int,
 
 
 def cache_block_otp(key_words, nonce3, block_ids, write_counters, layer_ids,
-                    words_per_block: int):
+                    words_per_block: int, planes: bool = True):
     """Keystream for paged KV-cache blocks — the cache analogue of
     ``tile_counters``: the OTP derives from the block's pool address, its
     write counter and the layer id, so any block seals/unseals independently
     and the (key, nonce, counter) triple is never reused for a given key.
 
     Derivation per ChaCha block ``c`` of a cache block ``b``:
-      counter = b * ceil(words_per_block/16) + c
+      counter = b * cpb + c,  cpb = ceil(words_per_block/16)
       nonce   = (nonce3[0] ^ layer_id, nonce3[1] ^ write_counter, nonce3[2])
+
+    Word order: with ``planes`` (K and V), word w of ChaCha block c pads
+    element ``w * cpb + c`` — the 16 state words are 16 planes of the block,
+    the order in which the paged attention kernel makes the pad in
+    registers; without it (MLA's latent stream), element ``c * 16 + w``.
 
     ``block_ids`` / ``write_counters`` / ``layer_ids`` broadcast together to
     a common shape S; returns a (*S, words_per_block) u32 keystream. XOR
@@ -106,7 +111,12 @@ def cache_block_otp(key_words, nonce3, block_ids, write_counters, layer_ids,
     nonces = (jnp.uint32(nonce3[0]) ^ jnp.repeat(lid, cpb),
               jnp.uint32(nonce3[1]) ^ jnp.repeat(wc, cpb),
               nonce3[2])
-    ks = C.chacha20_block(jnp.asarray(key_words, jnp.uint32), ctr, nonces)
+    key = jnp.asarray(key_words, jnp.uint32)
+    if planes:
+        ks = C.chacha20_words(key, ctr, nonces).reshape(16, -1, cpb)
+        ks = jnp.moveaxis(ks, 0, 1)                # (N, 16, cpb)
+    else:
+        ks = C.chacha20_block(key, ctr, nonces)
     return ks.reshape(shape + (cpb * 16,))[..., :words_per_block]
 
 

@@ -365,6 +365,17 @@ def attn_block_sub_apply(cfg: ModelConfig, kind: str, p, h, positions, mode, cac
     Attention reads [old cache ++ new kv]; the stale slot being overwritten
     is masked out automatically (invalid/rotated-out position)."""
     window = cfg.window if kind == "local_attn" else 0
+    if "attend" in (cache or {}):
+        # decode / chunk over the paged pool by the attention kernel: the
+        # tokens' fresh K/V join inside ``attend``, no dense view is built;
+        # the read's integrity verdict travels in the update record
+        k_new, v_new = L.project_kv(cfg, p, h, positions)
+        dt = jnp.dtype(cfg.dtype)
+        k_new, v_new = k_new.astype(dt), v_new.astype(dt)
+        out, ok = L.attention_apply(
+            cfg, p, h, positions, window=window,
+            attend=lambda q: cache["attend"](q, k_new, v_new))
+        return out, {"k_new": k_new, "v_new": v_new, "ok": ok}
     if mode == "decode":
         k_new, v_new = L.project_kv(cfg, p, h, positions)
         dt = cache["k"].dtype

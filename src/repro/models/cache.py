@@ -20,6 +20,8 @@ Two cache families live here:
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -182,9 +184,22 @@ def words_to_kv(words, dtype):
     raise TypeError(f"unsupported kv dtype {dtype}")
 
 
+def block_shape(cfg: ModelConfig, block_size: int):
+    """Trailing shape of one stream's pool block. A K or V block is a
+    (rows, lanes) stack of u32 tiles, a token a whole number of rows and
+    every row whole kv heads: the paged attention kernel DMAs it whole out
+    of the stacked pool (``kernels/sealed_paged_attention.py``). MLA's
+    latent blocks, which ``paged._dense_view`` gathers, stay flat."""
+    wpt = kv_words_per_token(cfg)
+    if cfg.mla is not None:
+        return (block_size * wpt,)
+    lanes = math.gcd(wpt, 128)
+    return (block_size * wpt // lanes, lanes)
+
+
 def paged_pool_spec(cfg: ModelConfig, num_blocks: int, block_size: int):
     """ShapeDtypeStructs of the paged pools: a tuple over pattern positions
-    of {"k", "v": (n_super, num_blocks, words_per_block) u32, "mac_k",
+    of {"k", "v": (n_super, num_blocks, *block_shape) u32, "mac_k",
     "mac_v": (n_super, num_blocks) u32, "lid": (n_super,) u32} — for MLA
     one latent stream {"c", "mac_c"} in place of K and V. ``lid`` is
     the globally unique layer id folded into the block keystream (nonce
@@ -193,13 +208,13 @@ def paged_pool_spec(cfg: ModelConfig, num_blocks: int, block_size: int):
     so the pool pytree structure is seal-agnostic, and stay zero unless the
     cache seal carries a MAC context."""
     n = cfg.n_superblocks()
-    wpb = block_size * kv_words_per_token(cfg)
+    blk = block_shape(cfg, block_size)
     streams = pool_streams(cfg)
     out = []
     for kind in cfg.pattern:
         assert kind in ("attn", "local_attn"), \
             f"paged pools cover attention layers only (got {kind!r})"
-        one = {s: jax.ShapeDtypeStruct((n, num_blocks, wpb), jnp.uint32)
+        one = {s: jax.ShapeDtypeStruct((n, num_blocks) + blk, jnp.uint32)
                for s in streams}
         one.update({f"mac_{s}": jax.ShapeDtypeStruct((n, num_blocks),
                                                      jnp.uint32)

@@ -311,18 +311,23 @@ def blockwise_attention(q, k, v, q_positions, k_positions, window: int,
 
 
 def attention_apply(cfg: ModelConfig, p, x, positions, *, window: int,
-                    impl: str = "naive", kv_override=None):
+                    impl: str = "naive", kv_override=None, attend=None):
     """Self-attention over x; returns (out, (k, v)) so callers can build caches.
 
     kv_override: (k, v, k_positions) — used at decode time to attend into a
-    cache instead of self-computed kv.
+    cache instead of self-computed kv. attend: a function of the roped
+    queries that returns their attention output and a second value, which
+    is returned in place of (k, v) — the paged serving path's read of the
+    cache pool, with its integrity verdict.
     """
     dt = cdtype(cfg)
     xb = x.astype(dt)
     q = dense(xb, p["wq"], "bsd,dhk->bshk", dt)
     q = constrain(q, "batch", None, "heads", "head_dim")
     scale = cfg.head_dim ** -0.5
-    if kv_override is None:
+    if attend is not None:
+        out, kv = attend(apply_rope(q, positions, cfg.rope_theta))
+    elif kv_override is None:
         k = dense(xb, p["wk"], "bsd,dhk->bshk", dt)
         v = dense(xb, p["wv"], "bsd,dhk->bshk", dt)
         k = constrain(k, "batch", None, "kv_heads", "kv_head_dim")

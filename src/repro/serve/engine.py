@@ -34,6 +34,7 @@ from repro.config import ModelConfig, SealConfig
 from repro.core import sealed_store as SS
 from repro.core.mac import SealedIntegrityError
 from repro.models import cache as MC
+from repro.models import paged as PG
 from repro.models import transformer as T
 from repro.models.cache import paged_pool_init
 from repro.runtime.fault import StragglerTimeout
@@ -207,8 +208,8 @@ class ServeEngine:
             "tokens": 0, "cow_copies": 0,
             "mac_checks": 0, "mac_failures": 0, "retries": 0,
             "shared_prefix_blocks": 0, "shared_prefix_tokens": 0,
-            "kv_blocks_gathered": 0, "kv_blocks_resident": 0,
-            "kv_blocks_reserved": 0,
+            "kv_blocks_gathered": 0, "kv_blocks_walked": 0,
+            "kv_blocks_resident": 0, "kv_blocks_reserved": 0,
             "fused_matmul_leaves": (len(self.sealed.fused_paths())
                                     if self.sealed else 0),
             "weights_plaintext_bytes_per_step": w_pt,
@@ -502,19 +503,28 @@ class ServeEngine:
         return (self._params_arg, self._pools, self._state)
 
     def _kv_blocks(self, running: List[int]) -> Dict[str, int]:
-        """What one decode tick's paged view reads of the cache, per layer,
-        from the host mirrors: every slot's whole table
-        (``blocks_gathered``), the blocks that hold the running slots'
-        tokens (``blocks_resident``), the blocks every slot holds
-        (``blocks_reserved``), and the running slots. Added to the
+        """What one decode tick reads of the cache, per layer, from the
+        host mirrors: every slot's whole table, what a dense view gathers
+        (``blocks_gathered``); the blocks the attention reads
+        (``blocks_walked``: the paged attention kernel walks the running
+        slots' resident blocks, a dense view gathers the whole tables —
+        averaged over the pattern's layers); the blocks that hold the
+        running slots' tokens (``blocks_resident``), the blocks every slot
+        holds (``blocks_reserved``), and the running slots. Added to the
         cumulative ``kv_blocks_*`` stats."""
         bs = self.block_size
-        kv = {"blocks_gathered": self.slots * (self.max_len // bs),
-              "blocks_resident": int(
-                  ((self._lengths[running] + bs - 1) // bs).sum()),
+        resident = int(((self._lengths[running] + bs - 1) // bs).sum())
+        full = self.slots * (self.max_len // bs)
+        pattern = self.cfg.pattern
+        kernel = sum(PG.kernel_reads(self.cfg, k) for k in pattern)
+        kv = {"blocks_gathered": full,
+              "blocks_walked": (kernel * resident
+                                + (len(pattern) - kernel) * full)
+                               // len(pattern),
+              "blocks_resident": resident,
               "blocks_reserved": sum(len(b) for b in self._slot_blocks),
               "running": len(running)}
-        for k in ("gathered", "resident", "reserved"):
+        for k in ("gathered", "walked", "resident", "reserved"):
             self._stats[f"kv_blocks_{k}"] += kv[f"blocks_{k}"]
         return kv
 

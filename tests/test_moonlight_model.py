@@ -279,8 +279,10 @@ def test_moe_and_mla_scopes_name_operations_and_change_none(monkeypatch):
     scoped = compiled().as_text()
     ops_ = re.findall(r'op_name="([^"]*)"', scoped)
     for scope in ("mla_absorb", "moe_route", "moe_experts", "moe_shared",
-                  "kv_view", "kv_append", "attention"):
+                  "kv_view", "kv_gather", "kv_mac", "kv_unseal", "kv_mask",
+                  "kv_append", "attention"):
         assert any(f"/{scope}/" in o for o in ops_), scope
+    assert any("/kv_view/kv_mac/" in o for o in ops_)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = compiled().as_text()

@@ -9,6 +9,8 @@ sealed + MAC pools, chunk widths 1 to 32, offsets at the start, middle and
 end of a block, rows that append nothing, and a row whose span is clamped
 at the end of its block table.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,7 +34,8 @@ def _append_word_roll(cfg, seal, pools, updates, tables, lengths, counts, wc):
     wc_out = wc
     for j in range(len(cfg.pattern)):
         pj, uj = pools[j], updates[j]
-        wpb = pj["k"].shape[-1]
+        shape_k = pj["k"].shape              # (n, NB, *block_shape)
+        wpb = math.prod(shape_k[2:])
         bs = wpb // wpt
         c = uj["k_new"].shape[2]
         nspan = 1 + (c + bs - 2) // bs
@@ -80,12 +83,13 @@ def _append_word_roll(cfg, seal, pools, updates, tables, lengths, counts, wc):
                 mac_words = mac_words.at[:, tgt].set(tags, mode="drop")
             return pool_words.at[:, tgt].set(out, mode="drop"), mac_words
 
-        nk, nmk = splice(pj["k"], pj["mac_k"], uj["k_new"],
+        flat = lambda x: x.reshape(x.shape[:2] + (wpb,))
+        nk, nmk = splice(flat(pj["k"]), pj["mac_k"], uj["k_new"],
                          seal.nonce_k if seal is not None else None)
-        nv, nmv = splice(pj["v"], pj["mac_v"], uj["v_new"],
+        nv, nmv = splice(flat(pj["v"]), pj["mac_v"], uj["v_new"],
                          seal.nonce_v if seal is not None else None)
-        new_pools.append({"k": nk, "v": nv, "mac_k": nmk, "mac_v": nmv,
-                          "lid": lid})
+        new_pools.append({"k": nk.reshape(shape_k), "v": nv.reshape(shape_k),
+                          "mac_k": nmk, "mac_v": nmv, "lid": lid})
         if j == 0:
             tgt = jnp.where(touched, pb, nb)
             wc_out = wc.at[tgt].add(jnp.uint32(1), mode="drop")

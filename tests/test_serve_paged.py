@@ -1,10 +1,12 @@
 """Paged, tile-sealed KV cache + continuous-batching scheduler.
 
-Layer 1 (pure functions, f32, exact): teacher-forced decode over the paged
-pools reproduces the contiguous cache's logits bit-for-bit, on a dense and
-a GQA head layout, with the pools plaintext or sealed — the seal is an XOR
-involution and invalid entries are zeroed after unseal, so the attention
-inputs are bitwise identical either way.
+Layer 1 (pure functions, f32): teacher-forced decode over the paged pools
+reproduces the contiguous cache's logits, on a dense and a GQA head layout,
+with the pools plaintext or sealed. The paged attention kernel sums the same
+terms as the contiguous cache's attention in another order (an online
+softmax over groups of blocks, the fresh token merged last), so the two
+agree to f32 rounding (1e-5), not bit for bit; sealed and plaintext pools
+feed the kernel the same plaintext, so they agree exactly with each other.
 
 Layer 2 (engine, bf16): the continuous scheduler under staggered arrivals
 completes everything, returns every block to the allocator, and a sealed
@@ -51,12 +53,18 @@ def _paged_teacher_forced(cfg, params, toks, seal):
     wc = jnp.asarray(wc)
     lengths = jnp.full((b,), PLEN, jnp.int32)
     ones = jnp.ones((b,), jnp.int32)
-    for t in range(STEPS):
-        step_tok = toks[:, PLEN + t][:, None]
+
+    @jax.jit
+    def step(pools, lengths, wc, step_tok):
         logits, updates, _ = PG.decode_logits(
             cfg, params, pools, tables, lengths, wc, step_tok, seal)
         pools, wc = PG.append_tokens(cfg, seal, pools, updates, tables,
                                      lengths, ones, wc)
+        return logits, pools, wc
+
+    for t in range(STEPS):
+        logits, pools, wc = step(pools, lengths, wc,
+                                 toks[:, PLEN + t][:, None])
         lengths = lengths + 1
         out.append(logits)
     return jnp.stack(out)
@@ -74,8 +82,6 @@ def test_paged_matches_contiguous_logits_exactly(kv_heads, sealed):
     seal = SS.cache_seal_config(bytes(range(32))) if sealed else None
     paged = _paged_teacher_forced(cfg, params, toks, seal)
 
-    # same padded cache width as the paged view, so reductions are ordered
-    # identically and the comparison can be exact
     mb = (PLEN + STEPS + BS - 1) // BS + 1
     logits, cache = T.prefill(cfg, params, {"tokens": toks[:, :PLEN]},
                               mb * BS)
@@ -85,8 +91,8 @@ def test_paged_matches_contiguous_logits_exactly(kv_heads, sealed):
                                          {"tokens": toks[:, PLEN + t][:, None]},
                                          jnp.int32(PLEN + t))
         ref.append(logits)
-    np.testing.assert_array_equal(np.asarray(paged),
-                                  np.asarray(jnp.stack(ref)))
+    np.testing.assert_allclose(np.asarray(paged), np.asarray(jnp.stack(ref)),
+                               rtol=1e-5, atol=1e-5)
 
 
 def _run_engine(cfg, params, seal_cache, reqs):
@@ -161,10 +167,10 @@ def test_continuous_scheduler_staggered_arrivals():
 @pytest.mark.parametrize("sealed", [False, True])
 def test_chunked_prefill_matches_one_shot_exactly(sealed):
     """A prompt prefilled in ragged fixed-width chunks produces the one-shot
-    ``prefill_logits`` output bit-for-bit: the dense paged view is
-    identity-indexed, so every chunk's keys land at view index == position —
-    the exact reduction layout of a contiguous prefill padded to the view
-    width."""
+    ``prefill_logits`` output: each chunk token attends the pool prefix
+    through the paged attention kernel and the chunk's own keys causally,
+    the same terms as a contiguous prefill summed in another order, so the
+    two agree to f32 rounding (1e-5)."""
     cfg = get_reduced("internlm2_1_8b").with_(dtype="float32")
     params = T.init_params(cfg, jax.random.key(0))
     rng = np.random.RandomState(3)
@@ -180,16 +186,22 @@ def test_chunked_prefill_matches_one_shot_exactly(sealed):
     wc = jnp.zeros((nb,), jnp.uint32)
     lengths = jnp.zeros((b,), jnp.int32)
     chunk_w, off, last = 5, 0, None
+
+    @jax.jit
+    def step(pools, lengths, wc, chunk, cl):
+        last, ups, _ = PG.chunk_logits(cfg, params, pools,
+                                       jnp.asarray(tables), lengths, wc,
+                                       chunk, cl, seal)
+        pools, wc = PG.append_tokens(cfg, seal, pools, ups,
+                                     jnp.asarray(tables), lengths, cl, wc)
+        return last, pools, wc
+
     while off < plen:
         n = min(chunk_w, plen - off)
         chunk = np.zeros((b, chunk_w), np.int32)
         chunk[:, :n] = toks[:, off:off + n]
         cl = jnp.full((b,), n, jnp.int32)
-        last, ups, _ = PG.chunk_logits(cfg, params, pools,
-                                       jnp.asarray(tables), lengths, wc,
-                                       jnp.asarray(chunk), cl, seal)
-        pools, wc = PG.append_tokens(cfg, seal, pools, ups,
-                                     jnp.asarray(tables), lengths, cl, wc)
+        last, pools, wc = step(pools, lengths, wc, jnp.asarray(chunk), cl)
         lengths = lengths + cl
         off += n
 
@@ -197,7 +209,8 @@ def test_chunked_prefill_matches_one_shot_exactly(sealed):
     pad[:, :plen] = toks
     ref, _ = PG.prefill_logits(cfg, params, jnp.asarray(pad),
                                jnp.full((b,), plen, jnp.int32))
-    np.testing.assert_array_equal(np.asarray(last), np.asarray(ref))
+    np.testing.assert_allclose(np.asarray(last), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("seal_cache", [False, True])

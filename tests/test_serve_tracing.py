@@ -1,10 +1,16 @@
 """Instrumentation of the serving path.
 
 Host spans: ``ServeEngine.step`` leaves ``serve.*`` spans on the profiler's
-trace, and ``serve.decode`` carries the paged view's block counters, taken
-from the host mirrors. Device scopes: the tick's operations carry the
-``kv_*`` and ``weight_decrypt`` scopes in their ``op_name`` metadata, and
-the scopes leave the optimised program unchanged.
+trace, and ``serve.decode`` carries the cache read's block counters, taken
+from the host mirrors: the paged attention kernel walks exactly the running
+slots' resident blocks, out of the whole tables a dense view would gather.
+Device scopes: the tick's operations carry the ``paged_attention``,
+``kv_append`` and ``weight_decrypt`` scopes in their ``op_name`` metadata,
+and the scopes leave the optimised program unchanged.
+(The dense view's ``kv_view`` scope and the ``kv_gather``, ``kv_mac``,
+``kv_unseal`` and ``kv_mask`` scopes inside it now name only MLA's and
+sliding-window layers' reads: ``tests/test_moonlight_model.py::
+test_moe_and_mla_scopes_name_operations_and_change_none`` checks them.)
 """
 import contextlib
 import glob
@@ -22,8 +28,8 @@ from repro.serve.engine import ServeEngine
 SPANS = ("serve.step", "serve.admit", "serve.chunk", "serve.chunk.readback",
          "serve.decode", "serve.decode.readback", "serve.integrity",
          "serve.evict", "serve.verify_weights")
-COUNTERS = ("blocks_gathered", "blocks_resident", "blocks_reserved",
-            "running")
+COUNTERS = ("blocks_gathered", "blocks_walked", "blocks_resident",
+            "blocks_reserved", "running")
 
 
 def _engine():
@@ -41,13 +47,15 @@ def _engine():
 
 
 def _expected_counters(eng):
-    """The decode counters from the host mirrors, before the tick."""
+    """The decode counters from the host mirrors, before the tick: the
+    kernel walks the running slots' resident blocks and no others."""
     bs = eng.block_size
     running = [i for i, r in enumerate(eng._active)
                if r is not None and eng._pending[i] is None]
+    resident = sum(-(-int(eng._lengths[i]) // bs) for i in running)
     return {"blocks_gathered": eng.slots * eng.max_len // bs,
-            "blocks_resident": sum(-(-int(eng._lengths[i]) // bs)
-                                   for i in running),
+            "blocks_walked": resident,
+            "blocks_resident": resident,
             "blocks_reserved": sum(len(b) for b in eng._slot_blocks),
             "running": len(running)}
 
@@ -86,10 +94,11 @@ def test_step_writes_every_serve_span_with_the_mirrors_counters(traced):
     got = [{k: v for k, v in e.stats if k in COUNTERS} for e in decodes]
     assert got == expected and len(got) == eng.stats["decode_steps"]
     # the stats carry the same counters, summed over the ticks
-    for k in ("gathered", "resident", "reserved"):
+    for k in ("gathered", "walked", "resident", "reserved"):
         assert eng.stats[f"kv_blocks_{k}"] == sum(
             c[f"blocks_{k}"] for c in expected)
-    assert 0 < eng.stats["kv_blocks_resident"] <= (
+    assert 0 < eng.stats["kv_blocks_walked"] == (
+        eng.stats["kv_blocks_resident"]) <= (
         eng.stats["kv_blocks_reserved"]) < eng.stats["kv_blocks_gathered"]
     # readbacks nest inside their dispatch's span, phases inside the step
     steps = [(e.start_ns, e.end_ns) for e in events if e.name == "serve.step"]
@@ -127,14 +136,14 @@ def test_scopes_name_the_ticks_operations_and_change_no_operation(
 
     scoped = compiled().as_text()
     ops = re.findall(r'op_name="([^"]*)"', scoped)
-    for scope in ("kv_view", "kv_gather", "kv_mac", "kv_unseal", "kv_mask",
-                  "kv_append", "weight_decrypt", "attention", "sampling"):
+    for scope in ("paged_attention", "kv_append", "weight_decrypt",
+                  "sampling"):
         assert any(f"/{scope}/" in o for o in ops), scope
-    assert any("/kv_view/kv_mac/" in o for o in ops)
+    assert not any("/kv_view/" in o for o in ops)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = compiled().as_text()
-    assert "kv_view" not in plain and "weight_decrypt" not in plain
+    assert "/paged_attention/" not in plain and "weight_decrypt" not in plain
     assert _program(scoped) == _program(plain)
 
 
@@ -143,5 +152,5 @@ def test_compiled_hlo_gives_both_programs_with_their_scopes(traced):
     assert set(texts) == {"tick", "chunk_step"}
     for prog, text in texts.items():
         ops = re.findall(r'op_name="([^"]*)"', text)
-        for scope in ("kv_view", "kv_unseal", "kv_append", "sampling"):
+        for scope in ("paged_attention", "kv_append", "sampling"):
             assert any(f"/{scope}/" in o for o in ops), (prog, scope)

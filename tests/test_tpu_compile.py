@@ -34,6 +34,7 @@ from repro.kernels import sealed_matmul as SM
 from repro.models import cache as MC
 from repro.models import layers as L
 from repro.models import paged as PG
+from repro.models import transformer as T
 
 
 @pytest.fixture(scope="module")
@@ -156,6 +157,84 @@ def test_append_tokens_has_no_word_gather_on_v5e(one_chip, seal, c, b):
         S(jax.ShapeDtypeStruct((b, 2 * wpb), jnp.uint32)),
         i32(b, 2 * wpb)).compile().as_text()
     assert _word_gathers(text, wpb)
+
+
+def _internlm2_cache(seal, sharding, nb=2049, bs=16):
+    cfg = get_config("internlm2_1_8b")
+    cs = (None if seal == "plain"
+          else SS.cache_seal_config(bytes(range(32)), verify=True))
+    pools = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(sd.shape, sd.dtype, sharding=sharding),
+        MC.paged_pool_spec(cfg, nb, bs))
+    return cfg, cs, pools
+
+
+@pytest.mark.parametrize("seal", ["plain", "sealed+mac"])
+@pytest.mark.parametrize("b,c", [(32, 1), (8, 32)])     # decode tick, chunk
+def test_sealed_paged_attention_compiles_for_v5e(one_chip, seal, b, c):
+    """internlm2_1_8b's cache read at the cells' shapes: 16 query heads over
+    8 kv heads of 128, 16-token blocks, 64-block tables over a stacked pool
+    of 24 layers x 2049 blocks; 32 rows of one query (the tick) and 8 rows
+    of a 32-token chunk."""
+    cfg, cs, pools = _internlm2_cache(seal, one_chip)
+    pool = pools[0]
+    mb = 64
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(q, k, v, tables, lengths, meta, wcb, expect, hk):
+        sealed = cs is not None
+        return ops.paged_attention(
+            q, k, v, tables, lengths, meta, block_size=16,
+            scale=128 ** -0.5, wcb=wcb if sealed else None,
+            expect=expect if sealed else None,
+            hash_keys=hk if sealed else None, interpret=False)
+
+    _compile(fn, S((b, c, 16, 128), jnp.bfloat16), pool["k"], pool["v"],
+             S((b, mb), jnp.int32), S((b,), jnp.int32), S((16,), jnp.int32),
+             S((b, mb), jnp.uint32), S((2, b, mb), jnp.uint32),
+             S((2, 64, 128), jnp.uint32))
+
+
+_VIEW = re.compile(r"bf16\[32,1024,8,128\]")
+_POOL_SLICE = re.compile(r"u32\[(?:1,)?2049,(?:8192|64,128)\]")
+
+
+def test_decode_tick_builds_no_dense_view_on_v5e(one_chip, monkeypatch):
+    """internlm2_1_8b's sealed, verified decode logits at the tick's shapes
+    (32 slots of 64 blocks): the optimised program holds no dense cache
+    view and no per-layer slice of the pool, which the kernel reads in
+    place. The check finds both in the dense view of one layer."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg, cs, pools = _internlm2_cache("sealed+mac", one_chip)
+    b, mb = 32, 64
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda sd: S(sd.shape, jnp.bfloat16),
+                          T.param_spec(cfg))
+    i32 = lambda *shape: S(shape, jnp.int32)
+    wc = S((2049,), jnp.uint32)
+
+    def tick(params, pools, tables, lengths, wc, tokens, live):
+        return PG.decode_logits(cfg, params, pools, tables, lengths, wc,
+                                tokens, cs, live=live)
+
+    text = jax.jit(tick).lower(params, pools, i32(b, mb), i32(b), wc,
+                               i32(b, 1), S((b, 1), jnp.bool_)
+                               ).compile().as_text()
+    assert "sealed_paged_attention" in text
+    assert _VIEW.findall(text) == [] and _POOL_SLICE.findall(text) == []
+
+    def view(pools, tables, lengths, wc):
+        pool = jax.tree.map(lambda a: a[3], pools[0])
+        return PG._dense_view(cfg, cs, pool, tables, lengths, wc)
+
+    text = jax.jit(view).lower(pools, i32(b, mb), i32(b), wc
+                               ).compile().as_text()
+    assert _VIEW.findall(text) and _POOL_SLICE.findall(text)
 
 
 @pytest.mark.parametrize("t,k,n", [(32, 2048, 1408), (32, 1408, 2048),
